@@ -181,11 +181,6 @@ def main() -> None:
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
-    if args.trace:
-        from benchmarks import serving_micro
-        serving_micro.run_trace(args.trace, smoke=True)
-        return
-
     if args.compare and only and "serving_micro" not in only:
         raise SystemExit("--compare needs serving_micro in the run "
                          "(drop --only or include serving_micro)")
@@ -203,6 +198,14 @@ def main() -> None:
                             cwd=repo_root).returncode
         if rc != 0:
             failures.append(("tier1", f"pytest exit {rc}"))
+    # only after pytest has exited: a chip belongs to one process, and this
+    # is the first time this process touches JAX
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    if args.trace:
+        from benchmarks import serving_micro
+        serving_micro.run_trace(args.trace, smoke=True)
+        return
     for name, modname in MODULES:
         if only and name not in only:
             continue

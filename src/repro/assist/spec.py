@@ -37,8 +37,6 @@ class AssistSpec:
                        host budget / HBM pools)
       cold_delta       delta-along-sequence transform before cold packing
       use_roofline_trigger  let the AWC trigger gate demotion
-      interpret        run Pallas attention kernels in interpret mode
-                       (True for CPU tests; set False on real TPUs)
 
     Prefetch task (paper 8.2):
       prefetch_lookahead       ticks-to-finish that arms the WaSP lookahead
@@ -77,7 +75,6 @@ class AssistSpec:
     max_cold_pages: Optional[int] = None
     cold_delta: bool = True
     use_roofline_trigger: bool = True
-    interpret: bool = True
     # prefetch task
     prefetch_lookahead: int = 2
     pages_per_prefetch_tick: int = 2

@@ -26,12 +26,14 @@ import dataclasses
 from typing import Any, Callable, Optional, Protocol, runtime_checkable
 
 from repro.obs.metrics import MetricsRegistry
+from repro.roofline.peaks import MODEL_TARGET
 
-# TPU v5e hardware constants (roofline/analysis.py shares these)
-PEAK_FLOPS = 197e12       # bf16 MXU
-HBM_BW = 819e9            # bytes/s
-ICI_BW = 50e9             # bytes/s per link
-HOST_BW = 16e9            # host<->HBM DMA (PCIe-class; prefetch transfers)
+# the modeled chip's published peaks (roofline/peaks.py)
+PEAK_FLOPS = MODEL_TARGET.bf16_flops
+HBM_BW = MODEL_TARGET.hbm_bw
+ICI_BW = MODEL_TARGET.ici_link_bw
+# assumed, not published: host<->HBM DMA (PCIe-class; prefetch transfers)
+HOST_BW = 16e9
 VPU_OPS = 4 * 8 * 128 * 940e6  # ~3.9e12 elementwise lanes/s (8x128x4 @ 940MHz)
 
 MIN_RATIO = 1.2           # paper 6: applications with >=10% compressibility;

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import pallas_interpret
+
 ENC_PARAMS = {"b2d1": (2, 1), "b4d1": (4, 1), "b4d2": (4, 2)}
 
 
@@ -94,7 +96,7 @@ def _compress_kernel(blocks_ref, base_ref, mask_ref, deltas_ref, ok_ref, *,
 
 
 def decompress_pallas(base, mask, deltas, *, enc: str, block_bytes: int = 512,
-                      bn: int | None = None, interpret: bool = True):
+                      bn: int | None = None, interpret: bool | None = None):
     """base u32[nb,1], mask u8[nb,W/8], deltas u8[nb,W*d] -> words."""
     wb, db = ENC_PARAMS[enc]
     W = block_bytes // wb
@@ -116,12 +118,12 @@ def decompress_pallas(base, mask, deltas, *, enc: str, block_bytes: int = 512,
         out_specs=pl.BlockSpec((bn, W), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((nb, W), out_dtype),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(base, mask, deltas)
 
 
 def compress_pallas(words, *, enc: str, block_bytes: int = 512,
-                    bn: int | None = None, interpret: bool = True):
+                    bn: int | None = None, interpret: bool | None = None):
     """words u16/u32[nb, W] -> (base, mask, deltas, ok) kernel layout."""
     wb, db = ENC_PARAMS[enc]
     W = block_bytes // wb
@@ -148,7 +150,7 @@ def compress_pallas(words, *, enc: str, block_bytes: int = 512,
             jax.ShapeDtypeStruct((nb, W * db), jnp.uint8),
             jax.ShapeDtypeStruct((nb, 1), jnp.uint8),
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(words)
 
 
@@ -224,7 +226,7 @@ def _packed_kernel(off_ref, enc_ref, stream_ref, out_ref, scratch, sem, *,
 
 
 def decompress_packed_pallas(stream, offsets, enc, *, block_bytes: int = 512,
-                             interpret: bool = True):
+                             interpret: bool | None = None):
     """Variable-rate BDI decode (4-byte-word subset + specials + raw).
 
     stream: uint8[S]; offsets: int32[nb]; enc: uint8[nb] ->
@@ -244,5 +246,5 @@ def decompress_packed_pallas(stream, offsets, enc, *, block_bytes: int = 512,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nb, block_bytes), jnp.uint8),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(offsets, enc.astype(jnp.int32), stream)

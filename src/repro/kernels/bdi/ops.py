@@ -25,13 +25,12 @@ def compress_for_kernel(x, enc: str, block_bytes: int = 512):
 
 
 @functools.partial(jax.jit, static_argnames=("enc", "block_bytes", "shape",
-                                             "dtype", "interpret"))
+                                             "dtype"))
 def decompress(base, mask, deltas, *, enc: str, block_bytes: int,
-               shape: tuple, dtype: str, interpret: bool = True):
+               shape: tuple, dtype: str):
     """Kernel-accelerated uniform-encoding decompression -> tensor."""
     words = bdi_kernel.decompress_pallas(
-        base, mask, deltas, enc=enc, block_bytes=block_bytes,
-        interpret=interpret)
+        base, mask, deltas, enc=enc, block_bytes=block_bytes)
     wb, _ = bdi_kernel.ENC_PARAMS[enc]
     blocks = bo.block_from_words(
         words if wb != 8 else words, wb, block_bytes)
@@ -40,13 +39,11 @@ def decompress(base, mask, deltas, *, enc: str, block_bytes: int,
     return bo.from_bytes(flat[:n], dtype, shape)
 
 
-@functools.partial(jax.jit, static_argnames=("enc", "block_bytes", "interpret"))
-def compress(words, *, enc: str, block_bytes: int = 512,
-             interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("enc", "block_bytes"))
+def compress(words, *, enc: str, block_bytes: int = 512):
     """Kernel-accelerated fixed-encoding compression (low-priority warp)."""
     return bdi_kernel.compress_pallas(words, enc=enc,
-                                      block_bytes=block_bytes,
-                                      interpret=interpret)
+                                      block_bytes=block_bytes)
 
 
 def compress_packed_for_kernel(x, block_bytes: int = 512):
@@ -55,13 +52,12 @@ def compress_packed_for_kernel(x, block_bytes: int = 512):
                                       allowed=KERNEL_ENCODINGS)
 
 
-@functools.partial(jax.jit, static_argnames=("block_bytes", "shape", "dtype",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_bytes", "shape", "dtype"))
 def decompress_packed(stream, offsets, enc, *, block_bytes: int, shape: tuple,
-                      dtype: str, interpret: bool = True):
+                      dtype: str):
     """Variable-rate kernel decode of a BDIPacked stream -> tensor."""
     blocks = bdi_kernel.decompress_packed_pallas(
-        stream, offsets, enc, block_bytes=block_bytes, interpret=interpret)
+        stream, offsets, enc, block_bytes=block_bytes)
     flat = blocks.reshape(-1)
     n = int(np.prod(shape)) * jnp.dtype(dtype).itemsize
     return bo.from_bytes(flat[:n], dtype, shape)

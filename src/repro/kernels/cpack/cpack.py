@@ -15,6 +15,8 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import pallas_interpret
+
 from repro.assist.schemes.cpack import (NDICT, CODE_ZERO, CODE_FULL0,
                                       CODE_PART0, CODE_ZEXT)
 
@@ -41,7 +43,7 @@ def _decompress_kernel(ok_ref, dict_ref, codes_ref, payload_ref, raw_ref,
 
 
 def decompress_pallas(ok, dict_, codes, payload, raw, *, block_bytes: int = 512,
-                      bn: int | None = None, interpret: bool = True):
+                      bn: int | None = None, interpret: bool | None = None):
     nb = ok.shape[0]
     W = block_bytes // 4
     if bn is None:  # largest power-of-two tile that divides nb
@@ -62,5 +64,5 @@ def decompress_pallas(ok, dict_, codes, payload, raw, *, block_bytes: int = 512,
         out_specs=pl.BlockSpec((bn, block_bytes), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((nb, block_bytes), jnp.uint8),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(ok, dict_, codes, payload, raw)

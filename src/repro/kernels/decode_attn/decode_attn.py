@@ -16,6 +16,8 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import pallas_interpret
+
 NEG_INF = -1e30
 
 
@@ -66,7 +68,7 @@ def _decode_kernel(len_ref, q_ref, k8_ref, ks_ref, v8_ref, vs_ref, o_ref,
 
 
 def decode_attn(q, k, ks, v, vs, lengths, *, bs: int = 128,
-                out_dtype=jnp.bfloat16, interpret: bool = True):
+                out_dtype=jnp.bfloat16, interpret: bool | None = None):
     """q: [B, H, D]; k/v: int8 or bf16 [B, G, S, D]; ks/vs: f32[B, G, S]
     (ignored when k is not int8); lengths: int32[B] -> [B, H, D]."""
     B, H, D = q.shape
@@ -104,6 +106,6 @@ def decode_attn(q, k, ks, v, vs, lengths, *, bs: int = 128,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, G, group, D), out_dtype),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(lengths, q4, k, ks, v, vs)
     return out.reshape(B, H, D)

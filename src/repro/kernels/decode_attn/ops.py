@@ -12,43 +12,38 @@ from repro.kernels.decode_attn import ref as da_ref
 quantize_kv = da_ref.quantize_kv
 
 
-@functools.partial(jax.jit, static_argnames=("bs", "interpret"))
-def decode_attn_q8(q, k8, ks, v8, vs, lengths, *, bs: int = 128,
-                   interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("bs",))
+def decode_attn_q8(q, k8, ks, v8, vs, lengths, *, bs: int = 128):
     """Flash-decode over int8 KV (CABA compressed-KV site)."""
-    return da.decode_attn(q, k8, ks, v8, vs, lengths, bs=bs,
-                          interpret=interpret)
+    return da.decode_attn(q, k8, ks, v8, vs, lengths, bs=bs)
 
 
-@functools.partial(jax.jit, static_argnames=("bs", "interpret"))
-def decode_attn_raw(q, k, v, lengths, *, bs: int = 128,
-                    interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("bs",))
+def decode_attn_raw(q, k, v, lengths, *, bs: int = 128):
     """Uncompressed-KV baseline with the identical flash schedule."""
     B, G, S, _ = k.shape
     dummy = jnp.ones((B, G, S), jnp.float32)
-    return da.decode_attn(q, k, dummy, v, dummy, lengths, bs=bs,
-                          interpret=interpret)
+    return da.decode_attn(q, k, dummy, v, dummy, lengths, bs=bs)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def paged_decode_attn_q8(q, k_pool, ks_pool, v_pool, vs_pool, block_table,
-                         lengths, *, interpret: bool = True):
+                         lengths):
     """Flash-decode gathering int8 KV pages through a block table
     (repro.cache warm tier; in-VMEM dequant after each page DMA)."""
     from repro.kernels.decode_attn import paged as pg
     return pg.paged_decode_attn(q, k_pool, ks_pool, v_pool, vs_pool,
-                                block_table, lengths, interpret=interpret)
+                                block_table, lengths)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_decode_attn_raw(q, k_pool, v_pool, block_table, lengths, *,
-                          interpret: bool = True):
+@jax.jit
+def paged_decode_attn_raw(q, k_pool, v_pool, block_table, lengths):
     """bf16-page baseline with the identical paged schedule."""
     from repro.kernels.decode_attn import paged as pg
     P, G, ps, _ = k_pool.shape
     dummy = jnp.ones((P, G, ps), jnp.float32)
     return pg.paged_decode_attn(q, k_pool, dummy, v_pool, dummy,
-                                block_table, lengths, interpret=interpret)
+                                block_table, lengths)
 
 
 # ---------------------------------------------------------------------------
@@ -58,8 +53,7 @@ def paged_decode_attn_raw(q, k_pool, v_pool, block_table, lengths, *,
 # A backend computes one layer's paged decode attention over the tiered
 # pools.  Uniform signature:
 #
-#   backend(q, pools_j, bt, lengths, *, window=0, has_warm=True,
-#           interpret=True) -> out
+#   backend(q, pools_j, bt, lengths, *, window=0, has_warm=True) -> out
 #
 #   q        bf16[B, H, dh]        this tick's queries (post-rope)
 #   pools_j  one layer's tier pools: kh/vh bf16[1+hot, G, ps, dh],
@@ -144,9 +138,8 @@ _masked_attn = masked_decode_attn      # registry-internal alias
 
 @register_attn_backend("gather")
 def attn_backend_gather(q, pools_j, bt, lengths, *, window: int = 0,
-                        has_warm: bool = True, interpret: bool = True):
+                        has_warm: bool = True):
     """jnp baseline: gather both tiers into a dense f32 cache, then mask."""
-    del interpret
     kh, vh = pools_j["kh"], pools_j["vh"]
     B = q.shape[0]
     G, ps = kh.shape[1], kh.shape[2]
@@ -172,7 +165,7 @@ def attn_backend_gather(q, pools_j, bt, lengths, *, window: int = 0,
 
 @register_attn_backend("pallas")
 def attn_backend_pallas(q, pools_j, bt, lengths, *, window: int = 0,
-                        has_warm: bool = True, interpret: bool = True):
+                        has_warm: bool = True):
     """The bf16 paged Pallas kernel (paged.py).  Warm pages must first be
     dequantized into a dense pool appended after the hot slots -- the
     materialization cost pallas_int8 exists to avoid."""
@@ -196,13 +189,12 @@ def attn_backend_pallas(q, pools_j, bt, lengths, *, window: int = 0,
     P, G, ps, _ = k_pool.shape
     dummy = jnp.ones((P, G, ps), jnp.float32)
     return pg.paged_decode_attn(q, k_pool, dummy, v_pool, dummy, bt, lengths,
-                                out_dtype=q.dtype, window=window,
-                                interpret=interpret)
+                                out_dtype=q.dtype, window=window)
 
 
 @register_attn_backend("pallas_int8")
 def attn_backend_pallas_int8(q, pools_j, bt, lengths, *, window: int = 0,
-                             has_warm: bool = True, interpret: bool = True):
+                             has_warm: bool = True):
     """Tiered Pallas kernel: hot tiles stream bf16, warm tiles stream int8
     and dequantize in VMEM right after the DMA (fused decompression)."""
     del has_warm                       # the select handles hot-only tables
@@ -210,7 +202,7 @@ def attn_backend_pallas_int8(q, pools_j, bt, lengths, *, window: int = 0,
     return pg.paged_decode_attn_tiered(
         q, pools_j["kh"], pools_j["vh"], pools_j["k8"], pools_j["ks"],
         pools_j["v8"], pools_j["vs"], bt, lengths, out_dtype=q.dtype,
-        window=window, interpret=interpret)
+        window=window)
 
 
 # ---------------------------------------------------------------------------
@@ -224,8 +216,8 @@ def attn_backend_pallas_int8(q, pools_j, bt, lengths, *, window: int = 0,
 # signature mirrors the GQA one but takes the two query factors the
 # absorbed form produces:
 #
-#   backend(q_lat, q_rope, pools_j, bt, lengths, *, scale, has_warm=True,
-#           interpret=True) -> o_lat f32[B, H, lora]
+#   backend(q_lat, q_rope, pools_j, bt, lengths, *, scale,
+#           has_warm=True) -> o_lat f32[B, H, lora]
 #
 # The caller (models/mla.py::mla_paged_decode) folds W_uk into q_lat
 # before and W_uv into o_lat after, so the backend is pure cache math.
@@ -283,11 +275,9 @@ def masked_latent_decode_attn(q_lat, q_rope, c, r, valid, scale):
 
 @register_latent_backend("gather")
 def latent_backend_gather(q_lat, q_rope, pools_j, bt, lengths, *,
-                          scale: float, has_warm: bool = True,
-                          interpret: bool = True):
+                          scale: float, has_warm: bool = True):
     """jnp baseline: gather both tiers into dense latent/rope caches, then
     run the reference absorbed attention."""
-    del interpret
     ch, rh = pools_j["kh"], pools_j["vh"]     # [1+hot, 1, ps, lora/dr]
     B = q_lat.shape[0]
     ps = ch.shape[2]

@@ -20,7 +20,18 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import pallas_interpret
+
 NEG_INF = -1e30
+
+
+def _valid(pos, len_b, window):
+    """Positions a decode query attends to: before ``len_b`` and, for
+    local attention (``window > 0``), within the last ``window``."""
+    valid = pos < len_b
+    if window:
+        valid &= pos >= len_b - window
+    return valid
 
 
 def _flash_step(s, np_, ps, window, len_b, q_ref, k, v, o_ref, m_s, l_s,
@@ -38,10 +49,8 @@ def _flash_step(s, np_, ps, window, len_b, q_ref, k, v, o_ref, m_s, l_s,
     logits = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * (D ** -0.5)  # [group, ps]
-    pos = s * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-    valid = pos < len_b
-    if window:                       # local attention: last `window` tokens
-        valid &= pos >= len_b - window
+    valid = _valid(s * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1),
+                   len_b, window)
     logits = jnp.where(valid, logits, NEG_INF)
 
     m_prev = m_s[...]
@@ -50,8 +59,11 @@ def _flash_step(s, np_, ps, window, len_b, q_ref, k, v, o_ref, m_s, l_s,
     p = jnp.exp(logits - m_new)
     p = jnp.where(valid, p, 0.0)
     # select, don't rely on the zero weight: invalid rows may hold
-    # non-finite garbage (trash-slot pages) and 0 * NaN = NaN
-    v = jnp.where(valid.reshape(ps, 1), v, 0.0)
+    # non-finite garbage (trash-slot pages) and 0 * NaN = NaN.  The row
+    # mask comes from its own iota: Mosaic cannot reshape the (1, ps) mask
+    # into (ps, 1)
+    v = jnp.where(_valid(s * ps + jax.lax.broadcasted_iota(
+        jnp.int32, (ps, 1), 0), len_b, window), v, 0.0)
     l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
@@ -63,14 +75,23 @@ def _flash_step(s, np_, ps, window, len_b, q_ref, k, v, o_ref, m_s, l_s,
         o_ref[0, 0] = (acc_s[...] / denom).astype(o_ref.dtype)
 
 
+def _dequant(x8_ref, sc_ref):
+    """f32 [ps, D] tile of an int8 page times its per-token scales.  The
+    scale block holds every KV head of the page ([1, G, ps]: a (1, ps)
+    block breaks the TPU's (8, 128) tiling rule), so the kernel picks its
+    own head's row."""
+    g = pl.program_id(1)
+    return x8_ref[0, 0].astype(jnp.float32) * sc_ref[0, g][:, None]
+
+
 def _paged_kernel(len_ref, bt_ref, q_ref, k8_ref, ks_ref, v8_ref, vs_ref,
                   o_ref, m_s, l_s, acc_s, *, np_: int, ps: int,
                   quantized: bool, window: int):
     b = pl.program_id(0)
     s = pl.program_id(2)
     if quantized:
-        k = k8_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0][:, None]
-        v = v8_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0][:, None]
+        k = _dequant(k8_ref, ks_ref)
+        v = _dequant(v8_ref, vs_ref)
     else:
         k = k8_ref[0, 0].astype(jnp.float32)              # [ps, D]
         v = v8_ref[0, 0].astype(jnp.float32)
@@ -80,7 +101,7 @@ def _paged_kernel(len_ref, bt_ref, q_ref, k8_ref, ks_ref, v8_ref, vs_ref,
 
 def paged_decode_attn(q, k_pool, ks_pool, v_pool, vs_pool, block_table,
                       lengths, *, out_dtype=jnp.bfloat16, window: int = 0,
-                      interpret: bool = True):
+                      interpret: bool | None = None):
     """q: [B, H, D]; pools: int8/bf16[P, G, ps, D] (+ f32[P, G, ps] scales,
     ignored unless int8); block_table: int32[B, n_pages] pool slots;
     lengths: int32[B] -> [B, H, D].  ``window > 0`` masks to the last
@@ -95,7 +116,7 @@ def paged_decode_attn(q, k_pool, ks_pool, v_pool, vs_pool, block_table,
                                quantized=quantized, window=window)
     # the KV tile for grid step (b, g, s) is page block_table[b, s]
     pool_map = lambda b, g, s, L, BT: (BT[b, s], g, 0, 0)
-    scale_map = lambda b, g, s, L, BT: (BT[b, s], g, 0)
+    scale_map = lambda b, g, s, L, BT: (BT[b, s], 0, 0)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -107,11 +128,11 @@ def paged_decode_attn(q, k_pool, ks_pool, v_pool, vs_pool, block_table,
                              memory_space=pltpu.VMEM),
                 pl.BlockSpec((1, 1, ps, D), pool_map,
                              memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, 1, ps), scale_map,
+                pl.BlockSpec((1, G, ps), scale_map,
                              memory_space=pltpu.VMEM),
                 pl.BlockSpec((1, 1, ps, D), pool_map,
                              memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, 1, ps), scale_map,
+                pl.BlockSpec((1, G, ps), scale_map,
                              memory_space=pltpu.VMEM),
             ],
             out_specs=pl.BlockSpec((1, 1, group, D),
@@ -123,7 +144,7 @@ def paged_decode_attn(q, k_pool, ks_pool, v_pool, vs_pool, block_table,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, G, group, D), out_dtype),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(lengths, block_table, q4, k_pool, ks_pool, v_pool, vs_pool)
     return out.reshape(B, H, D)
 
@@ -143,11 +164,9 @@ def _tiered_kernel(len_ref, bt_ref, q_ref, kh_ref, k8_ref, ks_ref, vh_ref,
     b = pl.program_id(0)
     s = pl.program_id(2)
     is_warm = bt_ref[b, s] < 0
-    k = jnp.where(is_warm,
-                  k8_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0][:, None],
+    k = jnp.where(is_warm, _dequant(k8_ref, ks_ref),
                   kh_ref[0, 0].astype(jnp.float32))       # [ps, D]
-    v = jnp.where(is_warm,
-                  v8_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0][:, None],
+    v = jnp.where(is_warm, _dequant(v8_ref, vs_ref),
                   vh_ref[0, 0].astype(jnp.float32))
     _flash_step(s, np_, ps, window, len_ref[b], q_ref, k, v, o_ref, m_s,
                 l_s, acc_s)
@@ -156,7 +175,7 @@ def _tiered_kernel(len_ref, bt_ref, q_ref, kh_ref, k8_ref, ks_ref, vh_ref,
 def paged_decode_attn_tiered(q, kh_pool, vh_pool, k8_pool, ks_pool, v8_pool,
                              vs_pool, block_table, lengths, *,
                              out_dtype=jnp.bfloat16, window: int = 0,
-                             interpret: bool = True):
+                             interpret: bool | None = None):
     """Mixed hot/warm paged flash-decode through an ENCODED block table.
 
     q: [B, H, D]; hot pools bf16[P_hot, G, ps, D]; warm pools
@@ -171,7 +190,7 @@ def paged_decode_attn_tiered(q, kh_pool, vh_pool, k8_pool, ks_pool, v8_pool,
     kernel = functools.partial(_tiered_kernel, np_=np_, ps=ps, window=window)
     hot_map = lambda b, g, s, L, BT: (jnp.maximum(BT[b, s], 0), g, 0, 0)
     warm_map = lambda b, g, s, L, BT: (jnp.maximum(-BT[b, s], 0), g, 0, 0)
-    wscale_map = lambda b, g, s, L, BT: (jnp.maximum(-BT[b, s], 0), g, 0)
+    wscale_map = lambda b, g, s, L, BT: (jnp.maximum(-BT[b, s], 0), 0, 0)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -185,13 +204,13 @@ def paged_decode_attn_tiered(q, kh_pool, vh_pool, k8_pool, ks_pool, v8_pool,
                              memory_space=pltpu.VMEM),
                 pl.BlockSpec((1, 1, ps, D), warm_map,
                              memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, 1, ps), wscale_map,
+                pl.BlockSpec((1, G, ps), wscale_map,
                              memory_space=pltpu.VMEM),
                 pl.BlockSpec((1, 1, ps, D), hot_map,
                              memory_space=pltpu.VMEM),
                 pl.BlockSpec((1, 1, ps, D), warm_map,
                              memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, 1, ps), wscale_map,
+                pl.BlockSpec((1, G, ps), wscale_map,
                              memory_space=pltpu.VMEM),
             ],
             out_specs=pl.BlockSpec((1, 1, group, D),
@@ -203,7 +222,7 @@ def paged_decode_attn_tiered(q, kh_pool, vh_pool, k8_pool, ks_pool, v8_pool,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, G, group, D), out_dtype),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(lengths, block_table, q4, kh_pool, k8_pool, ks_pool, vh_pool, v8_pool,
       vs_pool)
     return out.reshape(B, H, D)

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import pallas_interpret
+
 from repro.assist.schemes.fpc import PATTERNS, SEG_WORDS, SEG_BYTES
 
 _SEG_SIZES = np.array([int(p[2] * SEG_WORDS) for p in PATTERNS], np.int32)
@@ -84,7 +86,7 @@ def _fpc_kernel(off_ref, stream_ref, seg_enc_ref, out_ref, scratch, sem, *,
 
 
 def decompress_pallas(stream, offsets, seg_enc, *, block_bytes: int = 512,
-                      interpret: bool = True):
+                      interpret: bool | None = None):
     """stream u8[S]; offsets i32[nb]; seg_enc u8[nb, nseg] -> u8[nb, B]."""
     nb, nseg = seg_enc.shape
     kernel = functools.partial(_fpc_kernel, block_bytes=block_bytes)
@@ -103,5 +105,5 @@ def decompress_pallas(stream, offsets, seg_enc, *, block_bytes: int = 512,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nb, block_bytes), jnp.uint8),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(offsets, stream, seg_enc)

@@ -12,19 +12,16 @@ from repro.assist.schemes.fpc import FPCPacked, compress
 from repro.kernels.fpc import fpc as fpc_kernel
 
 
-@functools.partial(jax.jit, static_argnames=("block_bytes", "shape", "dtype",
-                                             "interpret"))
-def _decompress(stream, offsets, seg_enc, *, block_bytes, shape, dtype,
-                interpret=True):
+@functools.partial(jax.jit, static_argnames=("block_bytes", "shape", "dtype"))
+def _decompress(stream, offsets, seg_enc, *, block_bytes, shape, dtype):
     blocks = fpc_kernel.decompress_pallas(
-        stream, offsets, seg_enc, block_bytes=block_bytes,
-        interpret=interpret)
+        stream, offsets, seg_enc, block_bytes=block_bytes)
     flat = blocks.reshape(-1)
     n = int(np.prod(shape)) * jnp.dtype(dtype).itemsize
     return bo.from_bytes(flat[:n], dtype, shape)
 
 
-def decompress(c: FPCPacked, interpret: bool = True):
+def decompress(c: FPCPacked):
     return _decompress(c.stream, c.offsets, c.seg_enc,
                        block_bytes=c.block_bytes, shape=c.shape,
-                       dtype=c.dtype_name, interpret=interpret)
+                       dtype=c.dtype_name)

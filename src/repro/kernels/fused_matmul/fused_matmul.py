@@ -24,6 +24,8 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import pallas_interpret
+
 
 # ---------------------------------------------------------------------------
 # q8: block-scaled int8 weights
@@ -50,7 +52,7 @@ def _matmul_q8_kernel(x_ref, w8_ref, scale_ref, o_ref, acc, *, out_dtype,
 
 
 def matmul_q8(x, w8, scale, *, gk: int = 256, bm: int = 128, bn: int = 256,
-              out_dtype=jnp.bfloat16, interpret: bool = True):
+              out_dtype=jnp.bfloat16, interpret: bool | None = None):
     """y = x @ dequant(w8, scale).  x: [M, K] f32/bf16; w8: int8[K, N];
     scale: f32[K/gk, N].  bk is pinned to gk so scales factor per tile."""
     M, K = x.shape
@@ -74,7 +76,7 @@ def matmul_q8(x, w8, scale, *, gk: int = 256, bm: int = 128, bn: int = 256,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(x, w8, scale)
 
 
@@ -113,7 +115,7 @@ def _matmul_bdi_kernel(x_ref, base_ref, mask_ref, deltas_ref, o_ref, acc, *,
 
 
 def matmul_bdi(x, base, mask, deltas, *, bm: int = 128, bn: int = 256,
-               bk: int = 128, out_dtype=jnp.bfloat16, interpret: bool = True):
+               bk: int = 128, out_dtype=jnp.bfloat16, interpret: bool | None = None):
     """y = x @ bdi_decompress(base, mask, deltas).
 
     x: [M, K]; base: u32[K, N/256]; mask: u8[K, N/32]; deltas: u8[K, N].
@@ -142,5 +144,5 @@ def matmul_bdi(x, base, mask, deltas, *, bm: int = 128, bn: int = 256,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(x, base, mask, deltas)

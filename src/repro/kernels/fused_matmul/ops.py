@@ -10,18 +10,15 @@ from repro.kernels.fused_matmul import fused_matmul as fm
 from repro.kernels.fused_matmul import ref as fm_ref
 
 
-@functools.partial(jax.jit, static_argnames=("gk", "bm", "bn", "interpret"))
-def matmul_q8(x, w8, scale, *, gk: int = 256, bm: int = 128, bn: int = 256,
-              interpret: bool = True):
-    return fm.matmul_q8(x, w8, scale, gk=gk, bm=bm, bn=bn,
-                        interpret=interpret)
+@functools.partial(jax.jit, static_argnames=("gk", "bm", "bn"))
+def matmul_q8(x, w8, scale, *, gk: int = 256, bm: int = 128, bn: int = 256):
+    return fm.matmul_q8(x, w8, scale, gk=gk, bm=bm, bn=bn)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk"))
 def matmul_bdi(x, base, mask, deltas, *, bm: int = 128, bn: int = 256,
-               bk: int = 128, interpret: bool = True):
-    return fm.matmul_bdi(x, base, mask, deltas, bm=bm, bn=bn, bk=bk,
-                         interpret=interpret)
+               bk: int = 128):
+    return fm.matmul_bdi(x, base, mask, deltas, bm=bm, bn=bn, bk=bk)
 
 
 # layout builders (host-side, the paper's 5.3.1 initial setup)

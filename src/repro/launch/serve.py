@@ -31,6 +31,7 @@ import numpy as np
 import dataclasses
 
 from repro.kernels.decode_attn.ops import attn_backend_names
+from repro.launch.compile_cache import enable_compile_cache
 from repro.configs.base import DEFAULT_EOS_ID
 from repro.obs import Observability, ObsSpec
 from repro.obs.export import SnapshotWriter, serve_metrics
@@ -67,10 +68,6 @@ def main(argv=None):
     ap.add_argument("--attn-backend", default="gather",
                     choices=attn_backend_names(),
                     help="paged decode attention backend")
-    ap.add_argument("--no-interpret", dest="interpret",
-                    action="store_false",
-                    help="run Pallas backends as real kernels (TPU); "
-                         "default is interpret mode (CPU-safe)")
     ap.add_argument("--max-cold-pages", type=int, default=None,
                     help="cap on cold (host-offloaded) page ids; default "
                          "derives from the host budget / HBM pools")
@@ -269,4 +266,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

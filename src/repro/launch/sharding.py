@@ -75,26 +75,15 @@ def active_rules() -> Optional[ShardingRules]:
 
 def manual_shard_map(f, mesh, manual_axes, in_specs, out_specs, *,
                      auto_rest: bool = True):
-    """shard_map MANUAL over ``manual_axes`` across jax versions.
+    """``jax.shard_map`` MANUAL over ``manual_axes``.
 
-    jax >= 0.6 exposes ``jax.shard_map(..., axis_names=...)``; jax 0.4.x has
-    ``jax.experimental.shard_map.shard_map(..., auto=...)``.  With
-    ``auto_rest`` the remaining mesh axes stay under GSPMD (partial-manual).
-    CAUTION on 0.4.x: XLA's SPMD partitioner cannot partition a while loop
-    (``lax.scan``) inside a partial-manual region (``IsManualSubgroup``
-    check failure) -- bodies with control flow must pass
-    ``auto_rest=False`` (fully manual; unmentioned axes compute
-    redundantly) or keep the scan outside the manual region.
+    With ``auto_rest`` the remaining mesh axes stay under GSPMD
+    (partial-manual); without it the region is fully manual and the
+    unmentioned axes compute redundantly.
     """
-    manual = frozenset(manual_axes)
-    if hasattr(jax, "shard_map"):
-        kw = {"axis_names": manual} if auto_rest else {}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = frozenset(mesh.axis_names) - manual if auto_rest else frozenset()
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      auto=auto)
+    kw = {"axis_names": frozenset(manual_axes)} if auto_rest else {}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def shard(x, *logical):

@@ -199,8 +199,7 @@ def mla_init_cache(cfg: ArchConfig, batch: int, max_len: int, dtype=jnp.bfloat16
 
 
 def mla_paged_decode(cfg: ArchConfig, p, x, pools_j, bt, lengths, *,
-                     has_warm: bool = True, backend: str = "gather",
-                     interpret: bool = True):
+                     has_warm: bool = True, backend: str = "gather"):
     """Absorbed-form decode over LATENT PAGES (the "mla_latent" page kind).
 
     x: [B,1,D]; pools_j: one layer's tiered latent pools (kh = latent
@@ -231,7 +230,7 @@ def mla_paged_decode(cfg: ArchConfig, p, x, pools_j, bt, lengths, *,
     scale = (m.nope_head_dim + m.rope_head_dim) ** -0.5
     o_lat = attn_ops.get_latent_backend(backend)(
         q_lat, q_rope[:, 0].astype(jnp.float32), pools_j, bt, lengths + 1,
-        scale=scale, has_warm=has_warm, interpret=interpret)
+        scale=scale, has_warm=has_warm)
     o = jnp.einsum("bhr,rhv->bhv", o_lat, w_uv.astype(jnp.float32))
     out = jnp.einsum("bf,fd->bd",
                      o.reshape(B, H * m.v_head_dim).astype(x.dtype),

@@ -79,11 +79,10 @@ def build_model(cfg: ArchConfig, *, remat: bool = True) -> ModelFns:
 
     def paged_decode_step(params, pools, tokens, block_table, lengths,
                           state_slots=None, *, has_warm: bool = True,
-                          backend: str = "gather", interpret: bool = True):
+                          backend: str = "gather"):
         return T.stack_paged_decode_step(cfg, params, pools, tokens,
                                          block_table, lengths, state_slots,
-                                         has_warm=has_warm, backend=backend,
-                                         interpret=interpret)
+                                         has_warm=has_warm, backend=backend)
 
     def init_state(batch: int, max_len: int, kv_dtype=jnp.bfloat16,
                    kv_mode: str = "bf16", uniform_pos: bool = False):

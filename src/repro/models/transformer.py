@@ -313,8 +313,10 @@ def stack_init(rng, cfg: ArchConfig):
                 {} if kind == "shared_attn"
                 else block_init(jax.random.fold_in(kp, j), cfg, kind)
                 for j, kind in enumerate(plan.pattern))
-        per_block = [one(i) for i in range(plan.n_scan)]
-        params["scan"] = jax.tree.map(lambda *xs: jnp.stack(xs), *per_block)
+        # vmapped over the block index, each weight is drawn straight into
+        # its stacked [n_scan, ...] array: stacking per-block dicts would
+        # hold every layer's weights twice at peak
+        params["scan"] = jax.vmap(one)(jnp.arange(plan.n_scan))
     if plan.tail:
         params["tail_layers"] = [
             {} if kind == "shared_attn"
@@ -686,8 +688,7 @@ def paged_geometry(cfg: ArchConfig, page_size: int):
 
 
 def _gqa_paged_decode(cfg, p, x, pools_j, bt, lengths, *, has_warm: bool,
-                      backend: str = "gather", window: int = 0,
-                      interpret: bool = True):
+                      backend: str = "gather", window: int = 0):
     """One layer's paged GQA decode.
 
     x: [B, 1, D]; pools_j: one layer's slice of a tiers pool dict
@@ -711,7 +712,7 @@ def _gqa_paged_decode(cfg, p, x, pools_j, bt, lengths, *, has_warm: bool,
     pools_j = dict(pools_j, kh=kh, vh=vh)
     out = attn_ops.get_attn_backend(backend)(
         q[:, :, 0], pools_j, bt, lengths + 1, window=window,
-        has_warm=has_warm, interpret=interpret)           # [B, H, dh]
+        has_warm=has_warm)                                # [B, H, dh]
     out = out.reshape(B, 1, -1)
     return jnp.einsum("bsf,fd->bsd", out, Q.getw(p, "wo")), pools_j
 
@@ -747,8 +748,7 @@ _HOT_PLANES = ("kh", "vh", "sh")
 def block_apply_paged_decode(cfg: ArchConfig, kind: str, p, x, pools_j,
                              bt, lengths, *, state_slots=None,
                              has_warm: bool = True,
-                             backend: str = "gather",
-                             interpret: bool = True):
+                             backend: str = "gather"):
     """One layer's paged decode, dispatched on the layer's page kind:
     attention layers gather token pages (per-head KV or MLA latent);
     mamba2/rwkv6 layers read/write their state slab in place."""
@@ -761,13 +761,11 @@ def block_apply_paged_decode(cfg: ArchConfig, kind: str, p, x, pools_j,
     if cfg.mla is not None:
         out, pools_j = MLA.mla_paged_decode(cfg, p["attn"], h, pools_j, bt,
                                             lengths, has_warm=has_warm,
-                                            backend=backend,
-                                            interpret=interpret)
+                                            backend=backend)
     else:
         out, pools_j = _gqa_paged_decode(
             cfg, p["attn"], h, pools_j, bt, lengths, has_warm=has_warm,
-            backend=backend, window=paged_layer_window(cfg, kind),
-            interpret=interpret)
+            backend=backend, window=paged_layer_window(cfg, kind))
     x = x + out
     h = L.norm_apply(cfg, p["norm2"], x)
     out, _ = _ffn_apply(cfg, kind, p, h, moe_dropless=True)
@@ -777,8 +775,7 @@ def block_apply_paged_decode(cfg: ArchConfig, kind: str, p, x, pools_j,
 def stack_paged_decode_step(cfg: ArchConfig, params, pools, tokens, bt,
                             lengths, state_slots=None, *,
                             has_warm: bool = True,
-                            backend: str = "gather",
-                            interpret: bool = True):
+                            backend: str = "gather"):
     """One paged decode step over the full stack (head + scan + tail).
 
     pools: tuple of tier pool dicts, one per :func:`paged_segments` entry
@@ -812,7 +809,7 @@ def stack_paged_decode_step(cfg: ArchConfig, params, pools, tokens, bt,
         x, pj = block_apply_paged_decode(cfg, kind, p, x, pj, bt,
                                          lengths, state_slots=state_slots,
                                          has_warm=has_warm,
-                                         backend=backend, interpret=interpret)
+                                         backend=backend)
         new_pools[seg_idx] = dict(pools[seg_idx],
                                   **{k: v[None]
                                      for k, v in hot_of(pj).items()})
@@ -838,7 +835,7 @@ def stack_paged_decode_step(cfg: ArchConfig, params, pools, tokens, bt,
                 x, pj = block_apply_paged_decode(
                     cfg, kind, p, x, layer_pools[j], bt, lengths,
                     state_slots=state_slots, has_warm=has_warm,
-                    backend=backend, interpret=interpret)
+                    backend=backend)
                 hot_updates.append(hot_of(pj))
             return x, tuple(hot_updates)
 

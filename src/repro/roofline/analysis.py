@@ -29,10 +29,12 @@ from typing import Optional
 
 import numpy as np
 
-# TPU v5e hardware constants (assignment-given)
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s
-ICI_BW = 50e9                # bytes/s per link
+from repro.roofline.peaks import MODEL_TARGET
+
+# the modeled chip's published peaks (roofline/peaks.py)
+PEAK_FLOPS = MODEL_TARGET.bf16_flops
+HBM_BW = MODEL_TARGET.hbm_bw
+ICI_BW = MODEL_TARGET.ici_link_bw
 DCN_BW = 25e9                # bytes/s per chip across pods (assumed)
 
 _DTYPE_BYTES = {
@@ -240,7 +242,7 @@ def analyze(compiled, *, arch: str, shape: str, mesh_desc: str,
     hc = hlocost.analyze_text(hlo, n_devices=n_devices,
                               devices_per_pod=devices_per_pod or 0)
     try:
-        cost = hlocost.xla_cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         raw_flops = float(cost.get("flops", 0.0))
         raw_bytes = float(cost.get("bytes accessed", 0.0))
     except Exception:

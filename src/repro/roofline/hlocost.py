@@ -108,16 +108,6 @@ class HloCost:
         self.hbm_by_kind[kind] = self.hbm_by_kind.get(kind, 0.0) + nbytes
 
 
-def xla_cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized across jax versions: 0.4.x
-    returns a one-element list of per-partition dicts, newer jax the dict
-    itself."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def parse_module(text: str) -> dict[str, Computation]:
     comps: dict[str, Computation] = {}
     cur: Optional[Computation] = None

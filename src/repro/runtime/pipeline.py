@@ -36,10 +36,9 @@ def pipeline_fn(fn, mesh, axis: str, n_micro: int):
         rank = jax.lax.axis_index(axis)
         T = n_micro + n_stages - 1
         x0 = x_micro[0]
-        # carries start rank-varying (scan VMA typing; no-op pre-VMA jax)
-        pcast = getattr(jax.lax, "pcast", None) or (lambda x, *a, **k: x)
-        buf = pcast(jnp.zeros_like(x0), (axis,), to="varying")
-        outs = pcast(
+        # carries start rank-varying (scan VMA typing)
+        buf = jax.lax.pcast(jnp.zeros_like(x0), (axis,), to="varying")
+        outs = jax.lax.pcast(
             jnp.zeros((n_micro,) + x0.shape, x0.dtype), (axis,),
             to="varying")
         perm_fwd = [(i, i + 1) for i in range(n_stages - 1)]
@@ -70,10 +69,9 @@ def pipeline_fn(fn, mesh, axis: str, n_micro: int):
         return outs
 
     from repro.launch.sharding import manual_shard_map
-    # fully manual (auto_rest=False): the tick scan cannot live inside a
-    # partial-manual region on jax 0.4.x (XLA IsManualSubgroup crash); the
-    # per-rank body is local compute + pod collectives, so unmentioned mesh
-    # axes just compute redundantly on replicated inputs.
+    # fully manual (auto_rest=False): the per-rank body is local compute +
+    # pod collectives, so unmentioned mesh axes just compute redundantly on
+    # replicated inputs.
     return manual_shard_map(
         per_rank, mesh, {axis},
         (P(axis), P()),

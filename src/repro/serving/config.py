@@ -49,12 +49,9 @@ class ServeConfig:
     page_size: int = 16
     hbm_budget_mb: float = 64.0
     attn_backend: str = "gather"
-    # paged-engine execution knobs: interpret=False runs the Pallas
-    # backends as real kernels (TPU); max_cold_pages caps the cold page-id
-    # space (None = derive from the host budget / HBM pools).  Threaded
-    # through AssistSpec into EngineBase.from_config -- without these a
-    # build() engine was stuck in interpret mode with derived cold caps.
-    interpret: bool = True
+    # max_cold_pages caps the cold page-id space (None = derive from the
+    # host budget / HBM pools); threaded through AssistSpec into
+    # EngineBase.from_config
     max_cold_pages: Optional[int] = None
     # cross-request prefix reuse (paged engine; DESIGN.md 14): flat
     # aliases of the AssistSpec prefix knobs, same folding rules
@@ -85,7 +82,6 @@ class ServeConfig:
                 kv=self.kv_mode, paged=self.paged,
                 attn_backend=self.attn_backend, page_size=self.page_size,
                 hbm_budget_mb=self.hbm_budget_mb,
-                interpret=self.interpret,
                 max_cold_pages=self.max_cold_pages,
                 prefix_reuse=self.prefix_reuse,
                 prefix_max_nodes=self.prefix_max_nodes,
@@ -102,7 +98,6 @@ class ServeConfig:
                                  ("hbm_budget_mb",
                                   spec.budget_bytes / 2 ** 20),
                                  ("attn_backend", spec.attn_backend),
-                                 ("interpret", spec.interpret),
                                  ("max_cold_pages", spec.max_cold_pages),
                                  ("prefix_reuse", spec.prefix_reuse),
                                  ("prefix_max_nodes",

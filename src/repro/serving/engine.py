@@ -44,6 +44,16 @@ from repro.obs import Observability
 from repro.obs.metrics import TOKENS_BUCKETS
 
 
+def stage_host(mirror: np.ndarray) -> jax.Array:
+    """Device copy of a host mirror the engine keeps mutating.
+
+    ``jnp.asarray`` may alias an aligned numpy buffer without copying (the
+    CPU backend does), and the dispatched tick reads it asynchronously: an
+    in-place host update right after dispatch would then race the tick.
+    Staging a private copy makes the tick's input immutable."""
+    return jnp.asarray(mirror.copy())
+
+
 @dataclasses.dataclass
 class Request:
     rid: int
@@ -99,7 +109,6 @@ class EngineBase:
                 seed=scfg.seed, backend=spec.attn_backend,
                 use_roofline_trigger=spec.use_roofline_trigger,
                 max_cold_pages=spec.max_cold_pages,
-                interpret=spec.interpret,
                 prefix_reuse=spec.prefix_reuse,
                 prefix_max_nodes=spec.prefix_max_nodes,
                 prefix_min_pages=spec.prefix_min_pages,
@@ -328,7 +337,7 @@ class Engine(EngineBase):
         # stage host mirrors ABOVE the transfer guard; the tick counter is
         # staged only in strict mode (weak python int vs strong int32 hash
         # to different jit cache entries -- one compile per mode)
-        temps = jnp.asarray(self._temps)
+        temps = stage_host(self._temps)
         tick = (jnp.asarray(self._tick, jnp.int32)
                 if self._strict_transfers else self._tick)
         probe = self.obs.probe

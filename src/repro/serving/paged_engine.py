@@ -76,7 +76,7 @@ from repro.models import transformer as T
 from repro.models.model import ModelFns
 from repro.obs import Observability
 from repro.obs.metrics import TOKENS_BUCKETS
-from repro.serving.engine import EngineBase, Request
+from repro.serving.engine import EngineBase, Request, stage_host
 from repro.serving.resilience import (FaultInjector, Watchdog, read_snapshot,
                                       restore_engine, snapshot_engine,
                                       write_snapshot)
@@ -125,7 +125,7 @@ class PagedEngine(EngineBase):
                  controller: Optional[AssistController] = None,
                  use_roofline_trigger: bool = True,
                  max_cold_pages: Optional[int] = None,
-                 backend: str = "gather", interpret: bool = True,
+                 backend: str = "gather",
                  host_sync: bool = False,
                  prefix_reuse: bool = False,
                  prefix_max_nodes: int = 512,
@@ -147,7 +147,6 @@ class PagedEngine(EngineBase):
                              f"layers {bad}")
         self.model, self.params, self.cfg = model, params, cfg
         self.backend = backend
-        self.interpret = interpret
         tier = tier or TierConfig()
         if max_len % tier.page_size:
             raise ValueError("max_len must be a multiple of page_size")
@@ -357,7 +356,7 @@ class PagedEngine(EngineBase):
                     rng, tick):
             logits, pools = model.paged_decode_step(
                 params, pools, tokens[:, None], bt, lengths, state_slots,
-                has_warm=warm > 0, backend=backend, interpret=interpret)
+                has_warm=warm > 0, backend=backend)
             key = jax.random.fold_in(
                 jax.random.fold_in(rng, self.DECODE_STREAM), tick)
             nxt = self._select_token(logits[:, 0], temps, key)
@@ -901,9 +900,9 @@ class PagedEngine(EngineBase):
         # staged only in strict mode -- a python int (weak type) and an
         # int32 device scalar hash to different jit cache entries, so
         # conditional staging keeps one compile per mode
-        lengths = jnp.asarray(self._lengths)
-        state_slots = jnp.asarray(self._state_slots)
-        temps = jnp.asarray(self._temps)
+        lengths = stage_host(self._lengths)
+        state_slots = stage_host(self._state_slots)
+        temps = stage_host(self._temps)
         tick = (jnp.asarray(self.tick_no, jnp.int32)
                 if self._strict_transfers else self.tick_no)
         probe = self.obs.probe

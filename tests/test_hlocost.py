@@ -21,7 +21,7 @@ def test_cost_analysis_undercounts_scans():
         return y
 
     c = _compile(f, jax.ShapeDtypeStruct((128, 128), jnp.float32))
-    raw = hlocost.xla_cost_analysis(c)["flops"]
+    raw = c.cost_analysis()["flops"]
     assert raw == pytest.approx(2 * 128**3, rel=0.01)      # ONE body only
 
 
@@ -79,5 +79,5 @@ def test_hlocost_scan_matches_unscanned_model():
     got_scan = hlocost.analyze_text(scan_c.as_text(), n_devices=1)
     got_flat = hlocost.analyze_text(flat_c.as_text(), n_devices=1)
     assert got_scan.flops == pytest.approx(got_flat.flops, rel=0.02)
-    truth = hlocost.xla_cost_analysis(flat_c)["flops"]
+    truth = flat_c.cost_analysis()["flops"]
     assert got_flat.flops == pytest.approx(truth, rel=0.15)  # dots dominate

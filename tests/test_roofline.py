@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.roofline import analysis as RL
+from repro.roofline import peaks as PK
 
 
 SYNTH = """
@@ -85,3 +86,24 @@ def test_report_terms_math():
     assert rep.step_time_s == pytest.approx(1.0)
     assert rep.roofline_fraction == pytest.approx(1.0 / 1.75)
     assert rep.useful_flops_fraction == pytest.approx(0.5)
+
+
+def test_chip_peaks_keyed_by_device_kind():
+    """One peak table keyed by device_kind: a known TPU gets its row, an
+    unknown TPU kind is an error, and off a TPU the model target is used
+    by name -- the analytic constants are that same row."""
+    class Dev:
+        def __init__(self, platform, kind):
+            self.platform, self.device_kind = platform, kind
+
+    v5e = PK.chip_peaks(Dev("tpu", "TPU v5 lite"))
+    assert (v5e.bf16_flops, v5e.hbm_bw, v5e.hbm_bytes) == (197e12, 819e9,
+                                                           16e9)
+    with pytest.raises(KeyError, match="TPU v9"):
+        PK.chip_peaks(Dev("tpu", "TPU v9"))
+    assert PK.chip_peaks(Dev("cpu", "cpu")) is PK.MODEL_TARGET
+    from repro.assist import tasks
+    for mod in (RL, tasks):
+        assert (mod.PEAK_FLOPS, mod.HBM_BW, mod.ICI_BW) == (
+            PK.MODEL_TARGET.bf16_flops, PK.MODEL_TARGET.hbm_bw,
+            PK.MODEL_TARGET.ici_link_bw)

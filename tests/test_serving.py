@@ -101,28 +101,39 @@ def test_eos_default_single_constant():
         PagedEngine.__init__).parameters["eos_id"].default == DEFAULT_EOS_ID
 
 
-def test_interpret_and_cold_cap_reach_paged_engine(served_model):
-    """ISSUE 5 satellite: ``interpret`` and ``max_cold_pages`` thread
-    through ServeConfig/AssistSpec into EngineBase.from_config -- before
-    this, a TPU run built via ServeConfig.build() was stuck in interpret
-    mode and the cold cap was only reachable by direct construction."""
+def test_interpret_and_cold_cap_reach_paged_engine(served_model,
+                                                   monkeypatch):
+    """Pallas interpret mode comes from the platform, never from a
+    config field: a ServeConfig.build() engine on a TPU runs compiled
+    kernels.  ``max_cold_pages`` threads through ServeConfig/AssistSpec
+    into EngineBase.from_config."""
+    import inspect
     from repro.assist import AssistSpec
+    from repro.kernels import pallas_interpret
     from repro.serving.config import ServeConfig
+    from repro.serving.paged_engine import PagedEngine
     cfg, model, params = served_model
+    assert "interpret" not in ServeConfig.__dataclass_fields__
+    assert "interpret" not in AssistSpec.__dataclass_fields__
+    assert "interpret" not in inspect.signature(PagedEngine).parameters
+    assert pallas_interpret() is (jax.default_backend() != "tpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pallas_interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert pallas_interpret() is True
+    assert pallas_interpret(False) is False     # compile tests only
+    monkeypatch.undo()
     spec = AssistSpec(paged=True, enable_warm=True, enable_cold=True,
-                      max_cold_pages=5, interpret=False,
-                      use_roofline_trigger=False)
+                      max_cold_pages=5, use_roofline_trigger=False)
     scfg = ServeConfig(arch="qwen2-7b", reduced=True, slots=2, max_len=48,
                        assist=spec)
     eng, _, _ = scfg.build(model, params)
-    assert eng.interpret is False
     # the cap reached the pool sizing: page-id space = hot + warm + cap
     assert eng.pool.num_pages == (eng.store.hot_pages
                                   + eng.store.warm_pages + 5)
     # flat-alias spelling folds into the spec identically
     flat = ServeConfig(arch="qwen2-7b", reduced=True, paged=True,
-                       interpret=False, max_cold_pages=5)
-    assert flat.assist.interpret is False
+                       max_cold_pages=5)
     assert flat.assist.max_cold_pages == 5
 
 

@@ -35,3 +35,24 @@ def test_serve_cli_end_to_end():
         "--kv-mode", "int8"])
     assert len(done) == 5
     assert all(len(r.out) >= 1 for r in done)
+
+
+def test_compile_cache_dir_env_or_checkout(monkeypatch, tmp_path):
+    """The persistent compile cache lives where JAX_COMPILATION_CACHE_DIR
+    says (nothing set in code), else at one fixed path in the checkout."""
+    import pathlib
+    import jax
+    from repro.launch.compile_cache import REPO_CACHE_DIR, \
+        enable_compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(REPO_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(REPO_CACHE_DIR)
+        repo = pathlib.Path(__file__).resolve().parents[1]
+        assert REPO_CACHE_DIR == repo / ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
