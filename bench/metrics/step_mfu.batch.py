@@ -1,0 +1,6 @@
+"""step_mfu.batch: see ``bench.layer_metrics.step_mfu``."""
+from bench.layer_metrics import step_mfu
+
+
+def read(ctx):
+    return step_mfu(ctx)
