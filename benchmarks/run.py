@@ -18,8 +18,9 @@
     PYTHONPATH=src python -m benchmarks.run --smoke --compare \
         BENCH_serving.json
 
-    # Chrome trace-event JSON of one tiered serving scenario (Perfetto)
-    PYTHONPATH=src python -m benchmarks.run --trace out.json
+    # one tiered serving scenario under the profiler: engine spans and
+    # device ops on one timeline (TensorBoard / Perfetto)
+    PYTHONPATH=src python -m benchmarks.run --trace out_dir
 
 Each module prints its table and asserts its paper-validation bounds; a
 failed validation fails the run (EXPERIMENTS.md SS Paper-validation is
@@ -170,9 +171,10 @@ def main() -> None:
     ap.add_argument("--compare-absolute", action="store_true",
                     help="gate raw tokens/s instead of normalizing out "
                          "the fleet-wide shift (same-machine A/B)")
-    ap.add_argument("--trace", metavar="OUT_JSON", default=None,
-                    help="write a Chrome trace-event JSON of one tiered "
-                         "serving scenario and exit (view in Perfetto)")
+    ap.add_argument("--trace", metavar="DIR", default=None,
+                    help="profile one tiered serving scenario into DIR "
+                         "(jax.profiler.trace, with a Perfetto trace) "
+                         "and exit")
     ap.add_argument("--strict-transfers", action="store_true",
                     help="run serving benchmarks with the tick transfer "
                          "guard armed (jax.transfer_guard('disallow') "

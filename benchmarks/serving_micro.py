@@ -668,15 +668,16 @@ def run_sessions(smoke: bool = False, seed: int = 0):
     return results
 
 
-def run_trace(path: str, smoke: bool = True, seed: int = 0):
-    """Decode one tiered scenario with tracing on and write a Chrome
-    trace-event JSON (load in Perfetto / chrome://tracing).
+def run_trace(log_dir: str, smoke: bool = True, seed: int = 0):
+    """Decode one tiered scenario under ``jax.profiler.trace(log_dir)``:
+    the engine's ``engine.*`` spans and the device's programs land on one
+    timeline (a Perfetto trace beside the ``.xplane.pb``; open it at
+    https://ui.perfetto.dev).
 
-    Spans: per-request ``prefill`` + ``admit``/``retire`` instants on the
-    request track (tid 1), per-tick ``tick`` spans on the engine track.
-    Returns the number of events written (benchmarks/run.py --trace).
+    Returns the number of ``engine.step`` spans the trace holds, one per
+    tick (benchmarks/run.py --trace).
     """
-    from repro.obs import Observability, ObsSpec, validate_chrome_trace
+    from repro.obs.trace import read_spans
     cfg = reduced(ARCHS[ARCH])
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
@@ -688,11 +689,8 @@ def run_trace(path: str, smoke: bool = True, seed: int = 0):
                       hot_fraction=0.5, enable_warm=True, enable_cold=False,
                       use_roofline_trigger=False)
     scfg = ServeConfig(arch=ARCH, reduced=True, slots=2, max_len=48,
-                       eos_id=0, assist=spec,
-                       obs=ObsSpec(trace=True,
-                                   strict_transfers=STRICT_TRANSFERS))
-    obs = Observability(scfg.obs)
-    eng, _, _ = scfg.build(model, params, obs=obs)
+                       eos_id=0, assist=spec, obs=_obs_spec())
+    eng, _, _ = scfg.build(model, params)
     rng = np.random.default_rng(seed)
     n_req = 6 if smoke else 16
     for rid in range(n_req):
@@ -700,11 +698,15 @@ def run_trace(path: str, smoke: bool = True, seed: int = 0):
                            prompt=list(rng.integers(2, cfg.vocab_size,
                                                     int(rng.integers(18, 33)))),
                            max_new=4 if smoke else 8))
-    eng.run(max_ticks=2000)
-    n_events = validate_chrome_trace(obs.tracer.chrome_trace())
-    obs.tracer.write(path)
-    print(f"[serving_micro] trace PASS: {n_events} events -> {path}")
-    return n_events
+    with jax.profiler.trace(log_dir, create_perfetto_trace=True):
+        eng.run(max_ticks=2000)
+    n_steps = sum(e[0] == "engine.step" for e in read_spans(log_dir))
+    if n_steps != eng.tick_no:
+        raise AssertionError(f"{n_steps} engine.step spans for "
+                             f"{eng.tick_no} ticks")
+    print(f"[serving_micro] trace PASS: {n_steps} engine.step spans -> "
+          f"{log_dir}")
+    return n_steps
 
 
 def run_chaos(smoke: bool = False, seed: int = 0):

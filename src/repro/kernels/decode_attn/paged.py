@@ -145,6 +145,7 @@ def paged_decode_attn(q, k_pool, ks_pool, v_pool, vs_pool, block_table,
         ),
         out_shape=jax.ShapeDtypeStruct((B, G, group, D), out_dtype),
         interpret=pallas_interpret(interpret),
+        name="paged_decode_attn",
     )(lengths, block_table, q4, k_pool, ks_pool, v_pool, vs_pool)
     return out.reshape(B, H, D)
 
@@ -223,6 +224,7 @@ def paged_decode_attn_tiered(q, kh_pool, vh_pool, k8_pool, ks_pool, v8_pool,
         ),
         out_shape=jax.ShapeDtypeStruct((B, G, group, D), out_dtype),
         interpret=pallas_interpret(interpret),
+        name="paged_decode_attn_tiered",
     )(lengths, block_table, q4, kh_pool, k8_pool, ks_pool, vh_pool, v8_pool,
       vs_pool)
     return out.reshape(B, H, D)

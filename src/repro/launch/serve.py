@@ -22,10 +22,12 @@ from __future__ import annotations
 
 import argparse
 import atexit
+import contextlib
 import os
 import signal
 import time
 
+import jax
 import numpy as np
 
 import dataclasses
@@ -103,7 +105,7 @@ def main(argv=None):
                     help="mean turns per generated session")
     # observability (repro.obs, DESIGN.md 13)
     ap.add_argument("--no-obs", action="store_true",
-                    help="disable all telemetry (counters, probe, trace): "
+                    help="disable all telemetry (counters, probe, spans): "
                          "the overhead-free hot path")
     ap.add_argument("--metrics-port", type=int, default=None,
                     help="serve Prometheus text on this port at /metrics "
@@ -112,9 +114,10 @@ def main(argv=None):
                     help="write a periodic JSON metrics snapshot here")
     ap.add_argument("--snapshot-every", type=float, default=10.0,
                     help="snapshot period in seconds")
-    ap.add_argument("--trace", default=None,
-                    help="write a Chrome trace-event JSON (Perfetto) of "
-                         "the run here")
+    ap.add_argument("--trace", default=None, metavar="DIR",
+                    help="profile the serve loop into DIR "
+                         "(jax.profiler.trace, with a Perfetto trace): "
+                         "engine.* spans and device ops on one timeline")
     # resilience (repro.serving.resilience, DESIGN.md 17)
     ap.add_argument("--max-queue", dest="max_queue", type=int, default=None,
                     help="bounded admission queue: above this depth the "
@@ -135,8 +138,7 @@ def main(argv=None):
                          "raises instead of silently syncing")
     args = ap.parse_args(argv)
     fields = {f.name for f in dataclasses.fields(ServeConfig)}
-    spec = ObsSpec.off() if args.no_obs else ObsSpec(
-        trace=args.trace is not None)
+    spec = ObsSpec.off() if args.no_obs else ObsSpec()
     if args.strict_transfers:
         # composes with --no-obs: the guard is independent of telemetry
         spec = dataclasses.replace(spec, strict_transfers=True)
@@ -188,49 +190,54 @@ def main(argv=None):
 
     signal.signal(signal.SIGTERM, _drain)
     atexit.register(_drain)
-    rng = np.random.default_rng(scfg.seed)
-    t0 = time.time()
-    if args.n_sessions is not None:
-        # trace-driven multi-turn serving (repro.sessions): parked turns
-        # keep their pages; goodput is accounted per SLO class
-        if not scfg.assist.paged:
-            raise SystemExit("--sessions needs --paged (the session "
-                             "layer parks pages, not slots)")
-        import dataclasses as _dc
-        from repro.sessions import SessionManager, make_trace
-        sspec = _dc.replace(scfg.session_spec(),
-                            resume_policy=args.session_resume)
-        traces = make_trace(n_sessions=args.n_sessions, seed=scfg.seed,
-                            vocab_size=cfg.vocab_size,
-                            page_size=scfg.page_size,
-                            max_len=scfg.max_len,
-                            mean_turns=args.session_turns,
-                            max_new=scfg.max_new)
-        mgr = SessionManager(eng, sspec, traces)
-        rep = mgr.run()
-        dt = time.time() - t0
-        n_tok = eng.tokens_generated
-        print(f"\n{rep['sessions']} sessions / {rep['turns']} turns, "
-              f"{n_tok} tokens in {dt:.1f}s ({n_tok / max(dt, 1e-9):.1f} "
-              f"tok/s); resumes: {rep['resumes_replay']} replay / "
-              f"{rep['resumes_reprefill']} re-prefill, "
-              f"{rep['replayed_tokens']} tokens replayed")
-        for cls_name, c in rep["per_class"].items():
-            gp = (f"{c['goodput_frac']:.2f}"
-                  if c["goodput_frac"] is not None else "n/a")
-            print(f"  {cls_name:12s} turns={c['turns']:3d} "
-                  f"ok={c['turns_ok']:3d} viol={c['slo_violations']:3d} "
-                  f"goodput={gp} p95={c['p95_latency_ticks']} ticks "
-                  f"(budget {c['budget_ticks']})")
-        done = eng.finished
-    else:
-        for rid in range(scfg.requests):
-            plen = int(rng.integers(4, scfg.max_len - scfg.max_new - 1))
-            eng.submit(Request(rid=rid,
-                               prompt=list(rng.integers(2, cfg.vocab_size,
-                                                        plen)),
-                               max_new=scfg.max_new))
-        done = eng.run()
+    # --trace DIR: the serve loop runs under one profiler session, so the
+    # engine's spans and the device's programs share one timeline
+    profile = (jax.profiler.trace(args.trace, create_perfetto_trace=True)
+               if args.trace else contextlib.nullcontext())
+    with profile:
+        rng = np.random.default_rng(scfg.seed)
+        t0 = time.time()
+        if args.n_sessions is not None:
+            # trace-driven multi-turn serving (repro.sessions): parked turns
+            # keep their pages; goodput is accounted per SLO class
+            if not scfg.assist.paged:
+                raise SystemExit("--sessions needs --paged (the session "
+                                 "layer parks pages, not slots)")
+            import dataclasses as _dc
+            from repro.sessions import SessionManager, make_trace
+            sspec = _dc.replace(scfg.session_spec(),
+                                resume_policy=args.session_resume)
+            traces = make_trace(n_sessions=args.n_sessions, seed=scfg.seed,
+                                vocab_size=cfg.vocab_size,
+                                page_size=scfg.page_size,
+                                max_len=scfg.max_len,
+                                mean_turns=args.session_turns,
+                                max_new=scfg.max_new)
+            mgr = SessionManager(eng, sspec, traces)
+            rep = mgr.run()
+            dt = time.time() - t0
+            n_tok = eng.tokens_generated
+            print(f"\n{rep['sessions']} sessions / {rep['turns']} turns, "
+                  f"{n_tok} tokens in {dt:.1f}s ({n_tok / max(dt, 1e-9):.1f} "
+                  f"tok/s); resumes: {rep['resumes_replay']} replay / "
+                  f"{rep['resumes_reprefill']} re-prefill, "
+                  f"{rep['replayed_tokens']} tokens replayed")
+            for cls_name, c in rep["per_class"].items():
+                gp = (f"{c['goodput_frac']:.2f}"
+                      if c["goodput_frac"] is not None else "n/a")
+                print(f"  {cls_name:12s} turns={c['turns']:3d} "
+                      f"ok={c['turns_ok']:3d} viol={c['slo_violations']:3d} "
+                      f"goodput={gp} p95={c['p95_latency_ticks']} ticks "
+                      f"(budget {c['budget_ticks']})")
+            done = eng.finished
+        else:
+            for rid in range(scfg.requests):
+                plen = int(rng.integers(4, scfg.max_len - scfg.max_new - 1))
+                eng.submit(Request(rid=rid,
+                                   prompt=list(rng.integers(2, cfg.vocab_size,
+                                                            plen)),
+                                   max_new=scfg.max_new))
+            done = eng.run()
     dt = time.time() - t0
     n_tok = sum(len(r.out) for r in done)
     for r in sorted(done, key=lambda r: r.rid)[:8]:
@@ -254,9 +261,8 @@ def main(argv=None):
               f"({stats.get('exec_samples', 0)} fenced samples)")
     if aspec.paged:
         print(f"cache stats: {stats}")
-    if args.trace and eng.obs.tracer is not None:
-        eng.obs.tracer.write(args.trace)
-        print(f"chrome trace -> {args.trace}")
+    if args.trace:
+        print(f"profiler trace -> {args.trace}")
     if writer is not None:
         writer.stop()
         print(f"metrics snapshot -> {args.snapshot_json}")

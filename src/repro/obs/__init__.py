@@ -6,7 +6,8 @@ One substrate for every subsystem's measurements:
             disabled mode, process-global ``REGISTRY``
   probe     TickProbe: execution-true decode-tick sampling (dispatch_*
             every tick, exec_* via every-Nth-tick fence)
-  trace     Tracer: request-lifecycle spans as Chrome trace-event JSON
+  trace     Tracer: engine spans as profiler annotations, with per-phase
+            host-seconds counters
   export    prometheus_text / snapshot / SnapshotWriter / serve_metrics
   spec      ObsSpec: the declarative knob nested in ServeConfig
 
@@ -21,7 +22,7 @@ from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                log_buckets)
 from repro.obs.probe import TickProbe
 from repro.obs.spec import ObsSpec
-from repro.obs.trace import Tracer, validate_chrome_trace
+from repro.obs.trace import PHASES, Tracer
 
 
 class Observability:
@@ -43,8 +44,7 @@ class Observability:
             self.metrics = MetricsRegistry()
         else:
             self.metrics = NULL_REGISTRY
-        self.tracer = (Tracer(self.spec.trace_max_events)
-                       if self.spec.trace else None)
+        self.tracer = Tracer(self.metrics) if self.spec.counters else None
         self.probe = (TickProbe(self.spec.exec_sample_every,
                                 self.spec.probe_window,
                                 metrics=self.metrics)
@@ -63,6 +63,6 @@ class Observability:
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "NullRegistry",
     "NULL_METRIC", "NULL_REGISTRY", "REGISTRY", "SECONDS_BUCKETS",
-    "TOKENS_BUCKETS", "log_buckets", "TickProbe", "ObsSpec", "Tracer",
-    "validate_chrome_trace", "Observability",
+    "TOKENS_BUCKETS", "log_buckets", "TickProbe", "ObsSpec", "PHASES",
+    "Tracer", "Observability",
 ]

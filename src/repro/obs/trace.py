@@ -1,97 +1,93 @@
-"""Span tracing -- Chrome trace-event JSON, viewable in Perfetto.
+"""Engine spans on the profiler's clock, with per-phase host counters.
 
-The engine emits request-lifecycle spans (admission -> prefill -> tick
-spans -> retirement) with rid/lane/bucket attributes when tracing is
-enabled (``ObsSpec.trace``; default OFF -- the per-event append is cheap
-but not free, and traces are a debugging artifact, not a steady-state
-telemetry channel).
+Each span is a ``jax.profiler.TraceAnnotation`` named ``engine.<phase>``:
+it costs about a microsecond when no profiler session runs and records
+on the profiler's own clock when one does (``jax.profiler.trace`` /
+``start_trace``), on the same timeline as the device's programs and
+operations.  Span arguments (``rid``, ``bucket``, ``tick``...) land as
+stats on the host event.
 
-Events use the trace-event format's ``X`` (complete: ts + dur) and ``i``
-(instant) phases, microsecond timestamps relative to tracer construction.
-``chrome_trace()`` returns the ``{"traceEvents": [...]}`` object; load the
-written file at https://ui.perfetto.dev or chrome://tracing.
+Every span also adds its host seconds to one counter family,
+``engine_phase_seconds_total{phase=...}``, whether or not a profiler
+records.  Nested spans count once, in the innermost: a phase's counter
+holds its own time, less the time of the spans opened inside it, so the
+phases sum to the total of ``engine.step``.
 
-The tracer never calls into JAX: span boundaries time the HOST view of
-each phase (dispatch-side), which composes with the execution-true probe
-(obs/probe.py) rather than duplicating it.
+The phase vocabulary is closed (``PHASES``); each phase's counter handle
+is bound once, at construction.  ``instant`` marks a point event (retire,
+preempt, quarantine...) as a zero-length annotation and feeds no counter.
+``read_spans`` reads the ``engine.*`` events back from a written trace.
 """
 from __future__ import annotations
 
-import json
+import pathlib
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 
-#: trace-event process ids: one synthetic "process" per engine role so
-#: Perfetto groups the engine loop and request lifecycle into lanes
-PID_ENGINE = 0
+import jax
+
+#: engine phases, outermost first: ``step`` holds the rest; ``prefill``
+#: nests in ``admit`` and ``harvest_wait`` in ``harvest``
+PHASES = ("step", "lanes", "admit", "prefill", "movers", "dispatch",
+          "harvest", "harvest_wait")
 
 
 class Tracer:
-    """Bounded in-memory trace-event buffer."""
+    """Opens ``engine.*`` annotations and counts each phase's host time."""
 
-    def __init__(self, max_events: int = 200_000):
-        self.max_events = max_events
-        self.events: list[dict] = []
-        self.dropped = 0
-        self._t0 = time.perf_counter_ns()
-
-    def now_us(self) -> float:
-        """Microseconds since tracer construction (the trace clock)."""
-        return (time.perf_counter_ns() - self._t0) / 1e3
-
-    def _push(self, ev: dict):
-        if len(self.events) >= self.max_events:
-            self.dropped += 1
-            return
-        self.events.append(ev)
-
-    def complete(self, name: str, ts_us: float, dur_us: float,
-                 tid: int = 0, **args):
-        """One finished span (phase ``X``)."""
-        self._push({"name": name, "ph": "X", "pid": PID_ENGINE, "tid": tid,
-                    "ts": ts_us, "dur": max(dur_us, 0.0), "args": args})
-
-    def instant(self, name: str, tid: int = 0, **args):
-        """A point event (phase ``i``, thread scope)."""
-        self._push({"name": name, "ph": "i", "s": "t", "pid": PID_ENGINE,
-                    "tid": tid, "ts": self.now_us(), "args": args})
+    def __init__(self, metrics):
+        self._seconds = {p: metrics.counter(
+            "engine_phase_seconds_total",
+            "host seconds inside each engine phase, nested phases "
+            "counted once (in the innermost)", phase=p) for p in PHASES}
+        # seconds of the spans closed inside each open span
+        self._inner: list[float] = []
 
     @contextmanager
-    def span(self, name: str, tid: int = 0, **args):
-        """Context manager emitting one complete event around the body."""
-        t0 = self.now_us()
+    def span(self, phase: str, **args):
+        """Annotate the body as ``engine.<phase>`` and count its time."""
+        counter = self._seconds[phase]
+        self._inner.append(0.0)
+        t0 = time.perf_counter()
         try:
-            yield
+            with jax.profiler.TraceAnnotation(f"engine.{phase}", **args):
+                yield
         finally:
-            self.complete(name, t0, self.now_us() - t0, tid=tid, **args)
+            dt = time.perf_counter() - t0
+            counter.inc(max(dt - self._inner.pop(), 0.0))
+            if self._inner:
+                self._inner[-1] += dt
 
-    def chrome_trace(self) -> dict:
-        """The trace-event JSON object (Perfetto/chrome://tracing)."""
-        meta = [{"name": "process_name", "ph": "M", "pid": PID_ENGINE,
-                 "ts": 0, "args": {"name": "repro.serving"}}]
-        return {"traceEvents": meta + self.events,
-                "displayTimeUnit": "ms",
-                "otherData": {"dropped_events": self.dropped}}
-
-    def write(self, path) -> str:
-        """Serialize to ``path``; returns the path written."""
-        with open(path, "w") as f:
-            json.dump(self.chrome_trace(), f)
-        return str(path)
+    def instant(self, name: str, **args):
+        """A point event, ``engine.<name>``."""
+        with jax.profiler.TraceAnnotation(f"engine.{name}", **args):
+            pass
 
 
-def validate_chrome_trace(obj: dict) -> int:
-    """Assert ``obj`` is structurally valid trace-event JSON; returns the
-    event count.  The tier-1 smoke for ``benchmarks/run.py --trace`` uses
-    this, so format drift fails fast instead of breaking Perfetto loads."""
-    assert isinstance(obj, dict) and "traceEvents" in obj, obj.keys()
-    evs = obj["traceEvents"]
-    assert isinstance(evs, list) and evs
-    for ev in evs:
-        assert isinstance(ev, dict)
-        assert "ph" in ev and "name" in ev and "pid" in ev
-        if ev["ph"] in ("X", "i"):
-            assert isinstance(ev["ts"], (int, float)) and ev["ts"] >= 0
-        if ev["ph"] == "X":
-            assert isinstance(ev["dur"], (int, float)) and ev["dur"] >= 0
-    return len(evs)
+_NO_SPAN = nullcontext()
+
+
+def no_span(phase: str, **args):
+    """``Tracer.span`` of disabled telemetry: one shared no-op context."""
+    return _NO_SPAN
+
+
+def read_spans(log_dir) -> list[tuple[str, int, int, dict]]:
+    """The ``engine.*`` host events of the newest profiler trace under
+    ``log_dir``: ``(name, start_ns, end_ns, args)`` in start order."""
+    from jax.profiler import ProfileData
+    files = sorted(pathlib.Path(log_dir).rglob("*.xplane.pb"),
+                   key=lambda f: f.stat().st_mtime)
+    if not files:
+        raise FileNotFoundError(f"no .xplane.pb under {log_dir}")
+    out = []
+    for plane in ProfileData.from_file(str(files[-1])).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("engine."):
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                dict(ev.stats)))
+    return sorted(out, key=lambda e: e[1])

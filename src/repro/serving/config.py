@@ -65,8 +65,9 @@ class ServeConfig:
     # the spec's park switch (False = stateless re-prefill baseline)
     sessions: Optional[object] = None
     session_park: bool = True
-    # observability (repro.obs): counters + execution probe on by default,
-    # traces off; None folds to the default ObsSpec in __post_init__
+    # observability (repro.obs): counters, engine spans and the execution
+    # probe on by default; None folds to the default ObsSpec in
+    # __post_init__
     obs: Optional[ObsSpec] = None
     # resilience (repro.serving.resilience, DESIGN.md 17): bounded
     # admission queue (None = unbounded, SLO-aware shed above it), a

@@ -68,6 +68,12 @@ class Request:
     # terminal error status ("shed" / "checksum" / "nan" / ...); a request
     # retired with an error has no valid output stream
     error: Optional[str] = None
+    # stamped by the paged engine on time.perf_counter: submitted, admitted
+    # (prefill dispatched or a full prefix match taken), first token
+    # appended to ``out``.  The dense engine leaves them None.
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
 
 
 @dataclasses.dataclass

@@ -75,6 +75,7 @@ from repro.models import ssm as SSM
 from repro.models import transformer as T
 from repro.models.model import ModelFns
 from repro.obs import Observability
+from repro.obs.trace import no_span
 from repro.obs.metrics import TOKENS_BUCKETS
 from repro.serving.engine import EngineBase, Request, stage_host
 from repro.serving.resilience import (FaultInjector, Watchdog, read_snapshot,
@@ -136,6 +137,10 @@ class PagedEngine(EngineBase):
                  harvest_timeout_s: Optional[float] = None,
                  obs: Optional[Observability] = None):
         self.obs = obs if obs is not None else Observability()
+        # engine.* phase spans (repro.obs.trace); with telemetry off every
+        # span is one shared no-op context
+        self._span = (self.obs.tracer.span if self.obs.tracer is not None
+                      else no_span)
         # strict mode wraps the jitted tick dispatch in a transfer guard
         # (DESIGN.md 16); OFF shares one no-op context -- fence-free
         self._strict_transfers = bool(self.obs.spec.strict_transfers)
@@ -404,6 +409,7 @@ class PagedEngine(EngineBase):
             raise ValueError(
                 f"request {req.rid}: prompt ({len(req.prompt)}) + max_new "
                 f"({req.max_new}) exceeds max_len ({self.max_len})")
+        req.t_submit = time.perf_counter()
         super().submit(req)
 
     def resident_tokens(self) -> int:
@@ -556,45 +562,69 @@ class PagedEngine(EngineBase):
 
     # -- admission (preemption-by-demotion, never rejection) -----------------
 
+    def _consult_prefix(self, req: Request,
+                        protected: set[int]) -> Optional[list[int]]:
+        """Prefix-store consult (DESIGN.md 14): the pages of the longest
+        cached prefix of ``req.prompt`` (empty without a store, or under
+        the degraded plan, which pauses prefix admission), or None when
+        the matched prefix itself is poisoned."""
+        if self.prefix is None or self._degraded:
+            return []
+        matched = self.prefix.match(req.prompt)
+        self._release_prefix_pages()
+        if self.prefix_prefetch and matched:
+            # predictive WaSP re-promotion: matched radix pages that sit
+            # cold go through the prefetch queue AHEAD of the prefill
+            # dispatch, instead of promoting on first touch
+            cold_m = [p for p in matched if self.store.tier[p] == TIER_COLD]
+            if cold_m:
+                self.policy.schedule_prefetch(cold_m, kind="prefix")
+                try:
+                    self.policy.drain_prefetch(self.pool, self.store,
+                                               protected)
+                except ColdPageCorrupt as e:
+                    # scrub it; admission retries next tick with a fresh
+                    # match
+                    self._quarantine_page(e.pid, "checksum")
+                    return None
+                self.policy.account_swap_in(
+                    matched, [p for p in cold_m
+                              if self.store.tier[p] == TIER_COLD])
+        return matched
+
     def _admit_one(self, req: Request, protected: set[int]) -> bool:
+        """Admit one request into pages: prefill it, or take a full
+        prefix match.  The ``engine.admit`` span opens once the prefix
+        consult knows how many pages are shared."""
         if self._alloc_fault:
             # injected allocator exhaustion (FaultSpec "alloc"): surfaces
             # exactly like real pool pressure -- admission blocks this
             # tick and is retried on the next (retry is sound here)
             self._alloc_fault = False
             raise PoolExhausted("injected allocator exhaustion")
+        # matched pages map into the new table READ-ONLY via pool.share --
+        # they consume no free pages and no prefill work.  When the match
+        # covers every prompt position but the last, prefill is skipped
+        # outright and the first tick plays the final prompt token as a
+        # decode step.
+        matched = self._consult_prefix(req, protected)
+        if matched is None:
+            return False
+        plen = len(req.prompt)
+        with self._span("admit", rid=req.rid, prompt_len=plen,
+                        shared_pages=len(matched)):
+            if not self._place(req, matched, protected):
+                return False
+        req.t_admit = time.perf_counter()
+        return True
+
+    def _place(self, req: Request, matched: list[int],
+               protected: set[int]) -> bool:
+        """Pages for an admitted request, and its prefill unless the
+        match covers the prompt; False when the budgets hold no room."""
         plen = len(req.prompt)
         ps = self.pool.page_size
         npg = self.pool.pages_for(plen)
-        # prefix-store consult (DESIGN.md 14): matched pages map into the
-        # new table READ-ONLY via pool.share -- they consume no free pages
-        # and no prefill work.  When the match covers every prompt
-        # position but the last, prefill is skipped outright and the
-        # first tick plays the final prompt token as a decode step.
-        matched: list[int] = []
-        if self.prefix is not None and not self._degraded:
-            # (degraded plan pauses prefix admission: no match, no insert)
-            matched = self.prefix.match(req.prompt)
-            self._release_prefix_pages()
-            if self.prefix_prefetch and matched:
-                # predictive WaSP re-promotion: matched radix pages that
-                # sit cold go through the prefetch queue AHEAD of the
-                # prefill dispatch, instead of promoting on first touch
-                cold_m = [p for p in matched
-                          if self.store.tier[p] == TIER_COLD]
-                if cold_m:
-                    self.policy.schedule_prefetch(cold_m, kind="prefix")
-                    try:
-                        self.policy.drain_prefetch(self.pool, self.store,
-                                                   protected)
-                    except ColdPageCorrupt as e:
-                        # the matched prefix itself is poisoned: scrub it
-                        # and retry admission next tick with a fresh match
-                        self._quarantine_page(e.pid, "checksum")
-                        return False
-                    self.policy.account_swap_in(
-                        matched, [p for p in cold_m
-                                  if self.store.tier[p] == TIER_COLD])
         n_own = npg - len(matched)
         full_skip = (bool(matched) and not self.has_state
                      and len(matched) * ps >= plen - 1)
@@ -616,8 +646,6 @@ class PagedEngine(EngineBase):
         if self.has_state:
             spid = self.pool.allocate(self._state_rid(req.rid), 1)[0]
             self.store.place_hot_state(spid)
-        tr = self.obs.tracer
-        t0 = tr.now_us() if tr is not None else 0.0
         if full_skip:
             # every position 0..plen-2 is already cached; the first tick
             # feeds prompt[-1] as the lane token, writes its KV (COW if
@@ -627,10 +655,10 @@ class PagedEngine(EngineBase):
                                              req.max_new)
             self._c_pskips.inc()
             self._c_pskip_tokens.inc(plen)
-            if tr is not None:
-                tr.instant("admit", tid=1, rid=req.rid, prompt_len=plen)
-                tr.instant("prefix_hit", tid=1, rid=req.rid,
-                           shared_pages=len(matched), skipped=plen)
+            if self.obs.tracer is not None:
+                self.obs.tracer.instant("prefix_hit", rid=req.rid,
+                                        shared_pages=len(matched),
+                                        skipped=plen)
         else:
             # partial (or no) match: full prefill runs -- its recomputed
             # KV for matched positions scatters into the trash slot, the
@@ -638,20 +666,17 @@ class PagedEngine(EngineBase):
             # the caller's own prefill logits; the shared pages hold
             # bit-identical KV by causality + pad-invariant bucketing.
             batch = self._pad_prompt(req.prompt, ps)
-            tok, one_state = self._prefill(self.params, batch,
-                                           float(req.temperature), self.rng,
-                                           req.rid)
-            self.store.write_prefill([0] * len(matched) + slots,
-                                     self._segment_kv(one_state), S=plen)
-            if spid is not None:
-                self.store.write_state(spid, self._segment_state(one_state))
-            if tr is not None:
-                tr.instant("admit", tid=1, rid=req.rid, prompt_len=plen)
-                tr.complete("prefill", t0, tr.now_us() - t0, tid=1,
-                            rid=req.rid,
-                            bucket=int(batch["tokens"].shape[1]),
-                            prompt_len=plen, pages=npg,
-                            shared_pages=len(matched))
+            with self._span("prefill", rid=req.rid,
+                            bucket=batch["tokens"].shape[1]):
+                tok, one_state = self._prefill(self.params, batch,
+                                               float(req.temperature),
+                                               self.rng, req.rid)
+                self.store.write_prefill([0] * len(matched) + slots,
+                                         self._segment_kv(one_state),
+                                         S=plen)
+                if spid is not None:
+                    self.store.write_state(spid,
+                                           self._segment_state(one_state))
             # the sampled first token stays on device; it is appended to
             # req.out (and becomes a host int) at the next harvest
             self.resident[req.rid] = _RState(req, plen, tok[0],
@@ -842,22 +867,132 @@ class PagedEngine(EngineBase):
     def step(self) -> bool:
         """One tick: drain barrier, prefetch, schedule, admit, decode
         (sampling fused on device), then harvest the PREVIOUS tick's
-        tokens while this tick executes."""
+        tokens while this tick executes.
+
+        Each phase runs in an ``engine.*`` span (repro.obs.trace): lanes,
+        admit (nesting prefill), movers, dispatch, harvest (nesting
+        harvest_wait), all inside ``engine.step``."""
         self.tick_no += 1
-        self.admission_blocked = False
-        t_wall = time.perf_counter()
-        n_comp = self._jit_compiles()
-        tr = self.obs.tracer
-        t_tick = tr.now_us() if tr is not None else 0.0
-        fi = self.fault
-        if fi is not None:
-            # seeded fault sites drawn once per tick (storm-window gated)
-            if fi.should("alloc", self.tick_no):
-                self._alloc_fault = True
-            if fi.should("cold_payload", self.tick_no) and self.store.cold:
-                pids = sorted(self.store.cold.keys())
-                self.store.corrupt_cold(
-                    pids[fi.pick("cold_payload", len(pids))])
+        span = self._span
+        with span("step", tick=self.tick_no,
+                  lanes=self.n_lanes - self.lanes.count(None)):
+            self.admission_blocked = False
+            t_wall = time.perf_counter()
+            n_comp = self._jit_compiles()
+            fi = self.fault
+            if fi is not None:
+                # seeded fault sites drawn once per tick (storm-window
+                # gated)
+                if fi.should("alloc", self.tick_no):
+                    self._alloc_fault = True
+                if (fi.should("cold_payload", self.tick_no)
+                        and self.store.cold):
+                    pids = sorted(self.store.cold.keys())
+                    self.store.corrupt_cold(
+                        pids[fi.pick("cold_payload", len(pids))])
+            with span("lanes"):
+                protected = self._maintain_lanes()
+            self._admit_extra(protected)
+            active = [i for i, rid in enumerate(self.lanes)
+                      if rid is not None]
+            self._g_lanes.set(len(active))
+            self._g_parked.set(len(self.parked))
+            self._g_queued.set(len(self.queue))
+            if not active:
+                prev, self._inflight = self._inflight, None
+                with span("harvest"):
+                    got = self._harvest(prev)
+                self._feed_watchdog(t_wall, n_comp)
+                return got
+
+            with span("movers"):
+                self._push_lane_updates()
+                # pending tier copies precede the read
+                self._flush_movers_guarded()
+            probe = self.obs.probe
+            with span("dispatch"):
+                # stage every host mirror ABOVE the transfer guard: the
+                # guarded region must issue zero implicit h2d copies.  The
+                # tick counter is staged only in strict mode -- a python
+                # int (weak type) and an int32 device scalar hash to
+                # different jit cache entries, so conditional staging
+                # keeps one compile per mode
+                lengths = stage_host(self._lengths)
+                state_slots = stage_host(self._state_slots)
+                temps = stage_host(self._temps)
+                tick = (jnp.asarray(self.tick_no, jnp.int32)
+                        if self._strict_transfers else self.tick_no)
+                t0 = time.perf_counter() if probe is not None else 0.0
+                with self._tick_guard():
+                    nxt, pools = self._decode(self.params, self.store.pools,
+                                              self._tokens_dev, self._bt_dev,
+                                              lengths, state_slots, temps,
+                                              self.rng, tick)
+                if probe is not None:
+                    probe.record_dispatch(time.perf_counter() - t0)
+            if probe is not None and probe.should_fence(self.tick_no):
+                # execution-true sample: drain the device queue through
+                # this tick (dispatch start -> result ready, backlog
+                # included -- it is what a request actually waits)
+                # sync-ok: every-Nth execution-true probe fence
+                jax.block_until_ready(nxt)
+                probe.record_exec(time.perf_counter() - t0)
+            self.store.pools = pools
+            self._tokens_dev = nxt
+
+            snapshot = []
+            closing = 0
+            for i in active:
+                rid = self.lanes[i]
+                st = self.resident[rid]
+                st.length += 1              # host-known: the write position
+                self._lengths[i] += 1
+                if st.forced:
+                    # resumed-session replay: the cache just absorbed a
+                    # KNOWN token's KV; next tick's input comes from the
+                    # replay queue, the model's sample is discarded at
+                    # harvest (keep=False) and the budget does not advance
+                    st.last_tok = st.forced.popleft()
+                    self._dirty_tok.add(i)
+                    snapshot.append((i, rid, st.remaining, False))
+                    continue
+                st.remaining -= 1           # budget advance at dispatch
+                snapshot.append((i, rid, st.remaining, True))
+                if st.remaining <= 0:
+                    # budget exhausted (no readback needed): free the lane
+                    # now; the final token is in flight, retires at harvest
+                    self._vacate(i)
+                if st.remaining <= self.policy.cfg.prefetch_lookahead:
+                    closing += 1
+            res = self.resident_tokens()
+            self.peak_resident_tokens = max(self.peak_resident_tokens, res)
+            self._g_resident.set(res)
+            if self.host_sync:
+                prev, self._inflight = (nxt, snapshot), None
+            else:
+                prev, self._inflight = self._inflight, (nxt, snapshot)
+            with span("harvest"):
+                self._harvest(prev)
+            # WaSP lookahead: start promoting the next parked requests'
+            # cold TOKEN pages -- and their cold state slabs -- while the
+            # closing lanes finish, so swap-in promotion hides behind
+            # decode ticks
+            for rid in list(self.parked)[:max(closing, 0)]:
+                cold = [p for p in self.pool.table(rid)
+                        if self.store.tier[p] == TIER_COLD]
+                if self.has_state:
+                    spid = self.pool.table(self._state_rid(rid))[0]
+                    if self.store.tier[spid] == TIER_COLD:
+                        cold.append(spid)
+                if cold:
+                    self.policy.schedule_prefetch(cold, kind="lookahead")
+            self._feed_watchdog(t_wall, n_comp)
+            return True
+
+    def _maintain_lanes(self) -> set[int]:
+        """Land last tick's prefetches, fill free lanes, and make every
+        lane's request decodable; returns the pages this tick's decode
+        touches (``protected``)."""
         # drain barrier: land last tick's async prefetch promotions BEFORE
         # anything can read the warm pool this tick (assist prefetch task)
         self.store.commit_promotions()
@@ -880,99 +1015,9 @@ class PagedEngine(EngineBase):
                 self._vacate(i)                    # preempt by demotion
                 self.parked.appendleft(rid)
                 self._c_preempt.inc()
-                if tr is not None:
-                    tr.instant("preempt", tid=1, rid=rid, lane=i)
-        self._admit_extra(protected)
-        active = [i for i, rid in enumerate(self.lanes) if rid is not None]
-        self._g_lanes.set(len(active))
-        self._g_parked.set(len(self.parked))
-        self._g_queued.set(len(self.queue))
-        if not active:
-            prev, self._inflight = self._inflight, None
-            got = self._harvest(prev)
-            self._feed_watchdog(t_wall, n_comp)
-            return got
-
-        self._push_lane_updates()
-        self._flush_movers_guarded()  # pending tier copies precede the read
-        # stage every host mirror ABOVE the transfer guard: the guarded
-        # region must issue zero implicit h2d copies.  The tick counter is
-        # staged only in strict mode -- a python int (weak type) and an
-        # int32 device scalar hash to different jit cache entries, so
-        # conditional staging keeps one compile per mode
-        lengths = stage_host(self._lengths)
-        state_slots = stage_host(self._state_slots)
-        temps = stage_host(self._temps)
-        tick = (jnp.asarray(self.tick_no, jnp.int32)
-                if self._strict_transfers else self.tick_no)
-        probe = self.obs.probe
-        t0 = time.perf_counter() if probe is not None else 0.0
-        with self._tick_guard():
-            nxt, pools = self._decode(self.params, self.store.pools,
-                                      self._tokens_dev, self._bt_dev,
-                                      lengths, state_slots, temps,
-                                      self.rng, tick)
-        if probe is not None:
-            probe.record_dispatch(time.perf_counter() - t0)
-            if probe.should_fence(self.tick_no):
-                # execution-true sample: drain the device queue through
-                # this tick (dispatch start -> result ready, backlog
-                # included -- it is what a request actually waits)
-                # sync-ok: every-Nth execution-true probe fence
-                jax.block_until_ready(nxt)
-                probe.record_exec(time.perf_counter() - t0)
-        self.store.pools = pools
-        self._tokens_dev = nxt
-
-        snapshot = []
-        closing = 0
-        for i in active:
-            rid = self.lanes[i]
-            st = self.resident[rid]
-            st.length += 1                  # host-known: the write position
-            self._lengths[i] += 1
-            if st.forced:
-                # resumed-session replay: the cache just absorbed a KNOWN
-                # token's KV; next tick's input comes from the replay
-                # queue, the model's sample is discarded at harvest
-                # (keep=False) and the budget does not advance
-                st.last_tok = st.forced.popleft()
-                self._dirty_tok.add(i)
-                snapshot.append((i, rid, st.remaining, False))
-                continue
-            st.remaining -= 1               # budget advance at dispatch
-            snapshot.append((i, rid, st.remaining, True))
-            if st.remaining <= 0:
-                # budget exhausted (no readback needed): free the lane now;
-                # the final token is in flight and retires at harvest
-                self._vacate(i)
-            if st.remaining <= self.policy.cfg.prefetch_lookahead:
-                closing += 1
-        res = self.resident_tokens()
-        self.peak_resident_tokens = max(self.peak_resident_tokens, res)
-        self._g_resident.set(res)
-        if self.host_sync:
-            prev, self._inflight = (nxt, snapshot), None
-        else:
-            prev, self._inflight = self._inflight, (nxt, snapshot)
-        self._harvest(prev)
-        if tr is not None:
-            tr.complete("tick", t_tick, tr.now_us() - t_tick,
-                        tick=self.tick_no, lanes=len(active))
-        # WaSP lookahead: start promoting the next parked requests' cold
-        # TOKEN pages -- and their cold state slabs -- while the closing
-        # lanes finish, so swap-in promotion hides behind decode ticks
-        for rid in list(self.parked)[:max(closing, 0)]:
-            cold = [p for p in self.pool.table(rid)
-                    if self.store.tier[p] == TIER_COLD]
-            if self.has_state:
-                spid = self.pool.table(self._state_rid(rid))[0]
-                if self.store.tier[spid] == TIER_COLD:
-                    cold.append(spid)
-            if cold:
-                self.policy.schedule_prefetch(cold, kind="lookahead")
-        self._feed_watchdog(t_wall, n_comp)
-        return True
+                if self.obs.tracer is not None:
+                    self.obs.tracer.instant("preempt", rid=rid, lane=i)
+        return protected
 
     def _harvest(self, prev) -> bool:
         """Land the lagged tokens (one device_get, overlapping the tick
@@ -982,9 +1027,12 @@ class PagedEngine(EngineBase):
         if prev is None and not firsts:
             return False
         handles = [t for _, t in firsts] + ([prev[0]] if prev else [])
-        vals = self._device_get(handles)
+        with self._span("harvest_wait"):
+            vals = self._device_get(handles)
+        now = time.perf_counter()
         for (req, _), v in zip(firsts, vals):
             tok = int(np.asarray(v).ravel()[0])
+            req.t_first = now
             req.out.append(tok)
             st = self.resident.get(req.rid)
             if st is not None and isinstance(st.last_tok, jax.Array):
@@ -1012,6 +1060,8 @@ class PagedEngine(EngineBase):
                     # tick's input): retire with error, scrub pages
                     self._quarantine(rid, "nan")
                     continue
+                if not st.req.out:          # a full prefix match's first
+                    st.req.t_first = now
                 st.req.out.append(tok)
                 st.last_tok = tok
                 self.tokens_generated += 1
@@ -1027,7 +1077,7 @@ class PagedEngine(EngineBase):
         self.finished.append(st.req)
         self._c_retire.inc()
         if self.obs.tracer is not None:
-            self.obs.tracer.instant("retire", tid=1, rid=rid,
+            self.obs.tracer.instant("retire", rid=rid,
                                     out_tokens=len(st.req.out))
         for i, r in enumerate(self.lanes):
             if r == rid:
@@ -1049,7 +1099,7 @@ class PagedEngine(EngineBase):
             self._c_parks.inc()
             self._g_parked_sessions.set(len(self._parked_sessions))
             if self.obs.tracer is not None:
-                self.obs.tracer.instant("session_park", tid=1, rid=rid,
+                self.obs.tracer.instant("session_park", rid=rid,
                                         cached_len=st.length)
             return
         self._session_history.pop(rid, None)
@@ -1120,7 +1170,7 @@ class PagedEngine(EngineBase):
         self.policy.controller.set_degraded(flag)
         if self.obs.tracer is not None:
             self.obs.tracer.instant("degraded" if flag else "recovered",
-                                    tid=1, tick=self.tick_no)
+                                    tick=self.tick_no)
 
     def _quarantine(self, rid: int, reason: str):
         """Retire ``rid`` with error status and scrub every page it owns:
@@ -1149,7 +1199,7 @@ class PagedEngine(EngineBase):
         self._c_quarantine[reason].inc()
         self._g_parked_sessions.set(len(self._parked_sessions))
         if self.obs.tracer is not None:
-            self.obs.tracer.instant("quarantine", tid=1, rid=rid,
+            self.obs.tracer.instant("quarantine", rid=rid,
                                     reason=reason)
 
     def _quarantine_page(self, pid: int, reason: str):
@@ -1261,7 +1311,7 @@ class PagedEngine(EngineBase):
         self._g_parked_sessions.set(len(self._parked_sessions))
         self._touch(rid)
         if self.obs.tracer is not None:
-            self.obs.tracer.instant("session_resume", tid=1, rid=rid,
+            self.obs.tracer.instant("session_resume", rid=rid,
                                     cached_len=hlen, replay=len(replay))
         self.peak_resident_tokens = max(self.peak_resident_tokens,
                                         self.resident_tokens())
@@ -1288,7 +1338,7 @@ class PagedEngine(EngineBase):
                 self.parked.appendleft(rid)
                 self._c_preempt.inc()
                 if self.obs.tracer is not None:
-                    self.obs.tracer.instant("preempt", tid=1, rid=rid,
+                    self.obs.tracer.instant("preempt", rid=rid,
                                             lane=i, by="scheduler")
                 return True
         return False

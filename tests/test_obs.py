@@ -4,9 +4,10 @@ Covers, in order: the registry substrate and its export formats; the
 null-object disabled mode (overhead-free hot path); the execution-true
 tick probe; counter CONSERVATION on a live tiered engine (flow-balance
 invariants the registry must satisfy if the increments are placed right);
-token identity with observability on vs off; and the Chrome trace export.
+token identity with observability on vs off; and the engine's spans on
+the profiler's clock with their per-phase counters and request stamps.
 """
-import json
+import time
 
 import numpy as np
 import jax
@@ -14,10 +15,10 @@ import pytest
 
 from repro.configs import ARCHS, reduced
 from repro.models.model import build_model
-from repro.obs import (MetricsRegistry, NULL_METRIC, NULL_REGISTRY, ObsSpec,
-                       Observability, TickProbe, Tracer, log_buckets,
-                       validate_chrome_trace)
+from repro.obs import (MetricsRegistry, NULL_METRIC, NULL_REGISTRY, PHASES,
+                       ObsSpec, Observability, TickProbe, log_buckets)
 from repro.obs.export import prometheus_text, serve_metrics, snapshot
+from repro.obs.trace import read_spans
 from repro.serving.config import ServeConfig
 from repro.serving.engine import Request
 
@@ -289,65 +290,158 @@ def test_obs_enabled_fences_and_exec_dominates(served_model):
         s["exec_samples"]
 
 
-def test_token_identity_obs_on_off(served_model):
+def _profiled(log_dir):
+    """A profiler session that records host annotations but no Python
+    frames (the CPU traces stay small)."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return jax.profiler.trace(str(log_dir), profiler_options=opts)
+
+
+def test_token_identity_obs_on_off(served_model, tmp_path):
     """Telemetry must be a pure observer: identical greedy streams with
-    counters+probe on, everything off, and tracing on."""
+    counters+probe on, everything off, and a profiler recording the
+    engine's spans."""
     cfg, model, params = served_model
     outs = {}
     for key, spec in (("on", ObsSpec()), ("off", ObsSpec.off()),
-                      ("trace", ObsSpec(trace=True))):
-        eng, done = _run_stream(_tiered_scfg(spec), model, params,
-                                n_req=8, max_new=4)
+                      ("trace", ObsSpec())):
+        if key == "trace":
+            with _profiled(tmp_path):
+                eng, done = _run_stream(_tiered_scfg(spec), model, params,
+                                        n_req=8, max_new=4)
+            assert read_spans(tmp_path)
+        else:
+            eng, done = _run_stream(_tiered_scfg(spec), model, params,
+                                    n_req=8, max_new=4)
         outs[key] = {r.rid: tuple(r.out) for r in done}
     assert outs["on"] == outs["off"] == outs["trace"]
 
 
-# -- trace -------------------------------------------------------------------
+# -- spans, phase counters, request stamps -------------------------------------
 
-def test_tracer_chrome_format(tmp_path):
-    tr = Tracer(max_events=4)
-    t0 = tr.now_us()
-    tr.instant("admit", tid=1, rid=0)
-    tr.complete("prefill", t0, 120, tid=1, rid=0, bucket=32)
-    with tr.span("tick", tick=0):
-        pass
-    tr.instant("overflow-1", tid=1)
-    tr.instant("overflow-2", tid=1)          # > max_events: dropped
-    obj = tr.chrome_trace()
-    n = validate_chrome_trace(obj)
-    assert n == 5                       # 4 kept events + process-name meta
-    assert obj["otherData"]["dropped_events"] == 1
-    path = tmp_path / "trace.json"
-    tr.write(str(path))
-    assert validate_chrome_trace(json.loads(path.read_text())) == 5
+def _inside(ev, outer):
+    return outer[1] <= ev[1] and ev[2] <= outer[2]
 
 
 def test_engine_trace_spans(served_model, tmp_path):
-    """The engine emits the request-lifecycle span hierarchy: admit /
-    prefill / tick / retire, with rid+bucket attributes."""
+    """Under a profiler session the engine's spans land on the host
+    plane: one ``engine.step`` per tick, one ``engine.admit`` and one
+    nested ``engine.prefill`` per prefilled request with ``rid`` /
+    ``bucket`` stats, ``engine.harvest_wait`` nested in ``engine.harvest``
+    in ``engine.step``, and a retire instant per request."""
     cfg, model, params = served_model
-    eng, done = _run_stream(_tiered_scfg(ObsSpec(trace=True)), model,
-                            params, n_req=6)
-    tr = eng.obs.tracer
-    obj = tr.chrome_trace()
-    assert validate_chrome_trace(obj) > 0
-    names = {e["name"] for e in obj["traceEvents"] if e["ph"] != "M"}
-    assert {"admit", "prefill", "tick", "retire"} <= names
-    prefills = [e for e in obj["traceEvents"] if e["name"] == "prefill"]
-    assert len(prefills) == 6                    # one per admitted request
-    assert all(e["ph"] == "X" and "rid" in e["args"]
-               and "bucket" in e["args"] for e in prefills)
-    retires = [e for e in obj["traceEvents"] if e["name"] == "retire"]
-    assert sorted(e["args"]["rid"] for e in retires) == list(range(6))
-    path = tmp_path / "eng_trace.json"
-    tr.write(str(path))
-    assert json.loads(path.read_text())["traceEvents"]
+    eng, _, _ = _tiered_scfg(ObsSpec()).build(model, params)
+    rng = np.random.default_rng(0)
+    for rid in range(6):
+        eng.submit(Request(rid=rid,
+                           prompt=list(rng.integers(2, 400,
+                                                    int(rng.integers(18, 33)))),
+                           max_new=4))
+    t0 = eng.tick_no
+    with _profiled(tmp_path):
+        done = eng.run(max_ticks=3000)
+    assert len(done) == 6
+    evs = read_spans(tmp_path)
+    by = {}
+    for ev in evs:
+        by.setdefault(ev[0], []).append(ev)
+    steps = by["engine.step"]
+    assert len(steps) == eng.tick_no - t0
+    assert [e[3]["tick"] for e in steps] == list(range(t0 + 1,
+                                                       eng.tick_no + 1))
+    admits, prefills = by["engine.admit"], by["engine.prefill"]
+    assert sorted(e[3]["rid"] for e in admits) == list(range(6))
+    assert sorted(e[3]["rid"] for e in prefills) == list(range(6))
+    for pf in prefills:
+        assert pf[3]["bucket"] % 16 == 0 and pf[3]["bucket"] >= 18
+        (adm,) = [a for a in admits if a[3]["rid"] == pf[3]["rid"]]
+        assert _inside(pf, adm)
+        assert any(_inside(adm, st) for st in steps)
+    for hw in by["engine.harvest_wait"]:
+        assert any(_inside(hw, h) for h in by["engine.harvest"])
+        assert any(_inside(hw, st) for st in steps)
+    for name in ("engine.lanes", "engine.movers", "engine.dispatch",
+                 "engine.harvest"):
+        assert by[name] and all(any(_inside(e, st) for st in steps)
+                                for e in by[name])
+    assert sorted(e[3]["rid"] for e in by["engine.retire"]) == list(range(6))
+
+
+def test_phase_counters_sum_within_step_total(served_model):
+    """Nested phases count once, in the innermost span: the phases sum to
+    at most the host time of the steps, and to nearly all of it."""
+    cfg, model, params = served_model
+    eng, _, _ = _tiered_scfg(ObsSpec()).build(model, params)
+    rng = np.random.default_rng(1)
+    for rid in range(8):
+        eng.submit(Request(rid=rid,
+                           prompt=list(rng.integers(2, 400,
+                                                    int(rng.integers(18, 33)))),
+                           max_new=4))
+    wall = 0.0
+    while (eng.queue or eng.resident or eng._inflight is not None
+           or eng._pending_first):
+        t = time.perf_counter()
+        eng.step()
+        wall += time.perf_counter() - t
+    m = eng.obs.metrics
+    per = {p: m.get_value("engine_phase_seconds_total", phase=p)
+           for p in PHASES}
+    assert all(v > 0 for v in per.values()), per
+    assert 0.9 * wall <= sum(per.values()) <= wall
+
+
+def test_phase_counters_untouched_when_obs_off(served_model, tmp_path):
+    """ObsSpec.off(): no tracer, every span the shared no-op, no
+    ``engine.*`` event even while a profiler records, and no counter."""
+    from repro.obs.trace import no_span
+    cfg, model, params = served_model
+    with _profiled(tmp_path):
+        eng, done = _run_stream(_tiered_scfg(ObsSpec.off()), model, params,
+                                n_req=4, max_new=3)
+    assert len(done) == 4
+    assert eng.obs.tracer is None and eng._span is no_span
+    assert eng.obs.metrics.get_value("engine_phase_seconds_total",
+                                     phase="step") is None
+    assert read_spans(tmp_path) == []
+
+
+def test_request_timestamps_ordered(served_model):
+    """``t_submit <= t_admit <= t_first`` for every finished request,
+    the full-prefix-skip path included; the dense engine leaves them
+    unset."""
+    from repro.cache import TierConfig
+    from repro.serving.paged_engine import PagedEngine
+    cfg, model, params = served_model
+    rng = np.random.default_rng(2)
+    base = [int(t) for t in rng.integers(2, 400, 48)]
+    eng = PagedEngine(model, params, lanes=2, max_len=96,
+                      tier=TierConfig(page_size=16, hbm_budget_bytes=1 << 30,
+                                      enable_warm=False, enable_cold=False),
+                      use_roofline_trigger=False, prefix_reuse=True)
+    eng.submit(Request(rid=0, prompt=base + [5, 6, 7], max_new=4))
+    eng.run()
+    eng.submit(Request(rid=1, prompt=base[:32], max_new=4))  # full skip
+    eng.submit(Request(rid=2, prompt=base[:35] + [401] * 9, max_new=4))
+    done = eng.run()
+    assert eng.stats()["prefix"]["prefill_skips"] == 1
+    assert {r.rid for r in done} == {0, 1, 2}
+    for r in done:
+        assert r.out and r.error is None
+        assert r.t_submit <= r.t_admit <= r.t_first, r
+
+    dense = ServeConfig(arch="qwen2-7b", reduced=True, slots=2, max_len=48)
+    deng, _, _ = dense.build(model, params)
+    deng.submit(Request(rid=0, prompt=base[:20], max_new=3))
+    (r,) = deng.run()
+    assert r.out and r.t_submit is r.t_admit is r.t_first is None
 
 
 def test_serving_micro_trace_smoke(tmp_path):
-    """The benchmarks/run.py --trace path end to end (satellite f)."""
+    """The benchmarks/run.py --trace path end to end: one tiered scenario
+    under a profiler session, one ``engine.step`` per tick."""
     from benchmarks.serving_micro import run_trace
-    path = tmp_path / "serving_trace.json"
-    n = run_trace(str(path), smoke=True)
+    n = run_trace(str(tmp_path), smoke=True)
     assert n > 0
-    assert validate_chrome_trace(json.loads(path.read_text())) == n
+    assert sum(e[0] == "engine.step" for e in read_spans(tmp_path)) == n
