@@ -195,9 +195,10 @@ def attn_backend_pallas(q, pools_j, bt, lengths, *, window: int = 0,
 @register_attn_backend("pallas_int8")
 def attn_backend_pallas_int8(q, pools_j, bt, lengths, *, window: int = 0,
                              has_warm: bool = True):
-    """Tiered Pallas kernel: hot tiles stream bf16, warm tiles stream int8
-    and dequantize in VMEM right after the DMA (fused decompression)."""
-    del has_warm                       # the select handles hot-only tables
+    """Tiered Pallas kernel: each lane's live pages stream from their own
+    tier, hot pages as bf16, warm pages as int8 dequantized in VMEM right
+    after the DMA (fused decompression)."""
+    del has_warm        # the kernel finds a hot-only table from its signs
     from repro.kernels.decode_attn import paged as pg
     return pg.paged_decode_attn_tiered(
         q, pools_j["kh"], pools_j["vh"], pools_j["k8"], pools_j["ks"],

@@ -1,19 +1,25 @@
-"""Paged flash-decode Pallas kernel: KV gathered through a block table.
+"""Paged flash-decode Pallas kernels: KV read through a block table.
 
 Same online-softmax schedule as decode_attn.py, but the KV cache is a pool
-of fixed-size pages ``[P, G, ps, D]`` (the repro.cache warm tier) instead of
-a dense ``[B, G, S, D]`` slab.  The grid's S axis walks a request's *block
-table* (int32[B, n_pages], scalar-prefetched), so each KV tile's DMA source
-is ``pool[bt[b, s]]`` -- the address indirection the block table buys, with
-the int8 dequant still fused right after the HBM->VMEM move (the blocking
-high-priority decompression warp of the paper).
+of fixed-size pages ``[P, G, ps, D]`` (the repro.cache tiers) instead of a
+dense ``[B, G, S, D]`` slab.
 
-Unmapped table entries must point at a valid (e.g. trash) page; the length
-mask removes their contribution exactly as in the dense kernel.
+* ``paged_decode_attn`` (backend ``pallas``): one pool, the grid
+  ``(B, G, n_pages)`` walks a request's *block table* (int32[B, n_pages],
+  scalar-prefetched), so each KV tile's DMA source is ``pool[bt[b, s]]``.
+  Unmapped entries must point at a valid (e.g. trash) page; the length
+  mask removes their contribution.
+* ``paged_decode_attn_tiered`` (backend ``pallas_int8``): hot bf16 and
+  warm int8 pools through one encoded table, on the grid ``(B,)``.  Each
+  lane walks only its live pages, in double-buffered blocks of pages
+  copied by hand, each page fetched from the one tier it lives in, with
+  the int8 dequant fused right after the HBM->VMEM move (the blocking
+  high-priority decompression warp of the paper).
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -153,24 +159,228 @@ def paged_decode_attn(q, k_pool, ks_pool, v_pool, vs_pool, block_table,
 # -- tiered kernel: hot bf16 + warm int8 through one encoded table -----------
 #
 # Block-table entries use the repro.cache encoded-location convention:
-# loc > 0 hot slot, loc < 0 warm slot -loc, loc == 0 trash.  Each grid step
-# DMAs BOTH candidate tiles (hot slot max(loc,0), warm slot max(-loc,0)) and
-# selects in VMEM, dequantizing the warm tile right after the move -- the
-# CABA fused-decompression contract without materializing a dense bf16 copy
-# of the warm tier (which is what the plain bf16 kernel must do).
+# loc > 0 hot slot, loc < 0 warm slot -loc, loc == 0 trash.  One grid step
+# serves one lane with all its KV heads.  The pools stay in HBM and the
+# kernel walks only the lane's live pages -- from the first page the window
+# reaches to ceil(len / ps) -- in blocks of ``ppb`` pages, double-buffered:
+# while one block is attended, the next block's pages are in flight.  Each
+# page is one DMA per tensor of all G heads, from the one tier it lives in
+# (a hot bf16 page or a warm int8 page); trash entries and pages outside
+# the live range are never fetched.  A block of hot pages is attended
+# straight from its DMA buffers.  A block holding a warm or a trash page is
+# staged in f32 first, and its warm keys are dequantized in VMEM right
+# after the move -- the CABA fused-decompression contract without a dense
+# bf16 copy of the warm tier (which the plain bf16 kernel needs).
+#
+# The warm scales reach the kernel as key-major rows, f32[B, G, keys]:
+# Mosaic DMAs only slices whose last dim is a multiple of 128, which a
+# [G, ps] scale page is not.  The rows are gathered only when the table
+# holds a warm entry, and a block DMAs its rows only when it holds a warm
+# page.  A scale multiplies a key's logit and its softmax weight, which is
+# the same as multiplying its K and V rows.
 
-def _tiered_kernel(len_ref, bt_ref, q_ref, kh_ref, k8_ref, ks_ref, vh_ref,
-                   v8_ref, vs_ref, o_ref, m_s, l_s, acc_s, *, np_: int,
-                   ps: int, window: int):
+#: keys per block: enough work per step to hide the next block's DMAs
+_BLOCK_TOKENS = 256
+#: VMEM the kernel's page buffers may take
+_VMEM_BUDGET = 8 << 20
+#: Mosaic's lane tile: a DMA'd slice's last dim is a multiple of it, so a
+#: block's scale rows start at one
+_LANE_TILE = 128
+
+
+def _pages_per_block(ps: int, G: int, D: int, n_pages: int) -> int:
+    """Pages per block: ``_BLOCK_TOKENS`` keys within ``_VMEM_BUDGET`` (two
+    buffers of hot bf16 and warm int8 K and V pages, the f32 staging of a
+    mixed block, scale rows), a multiple of 128 keys unless the table is
+    one block, and never more than the table."""
+    per_page = G * ps * (20 * D + 32)
+    ppb = max(1, min(_BLOCK_TOKENS // ps, _VMEM_BUDGET // per_page))
+    step = _LANE_TILE // math.gcd(ps, _LANE_TILE)
+    return min(n_pages, max(step, ppb // step * step))
+
+
+def _scale_rows(ks_pool, vs_pool, bt, width: int):
+    """Each lane's warm K and V scales, key-major: f32[B, G, width], the
+    key at position t in column t.  Hot and trash keys read whatever slot
+    0 holds; the kernel never uses them."""
+    B, NP = bt.shape
+    _, G, ps = ks_pool.shape
+
+    def gather():
+        idx = jnp.maximum(-bt, 0)
+        return tuple(
+            jnp.pad(sc[idx].transpose(0, 2, 1, 3).reshape(B, G, NP * ps),
+                    ((0, 0), (0, 0), (0, width - NP * ps)))
+            for sc in (ks_pool, vs_pool))
+
+    def none():
+        return (jnp.zeros((B, G, width), jnp.float32),) * 2
+
+    return jax.lax.cond(jnp.any(bt < 0), gather, none)
+
+
+def _tiered_kernel(len_ref, bt_ref, q_ref, kh_hbm, vh_hbm, k8_hbm, v8_hbm,
+                   ksr_hbm, vsr_hbm, o_ref, kh_buf, vh_buf, k8_buf, v8_buf,
+                   ks_buf, vs_buf, k_st, v_st, m_s, l_s, acc_s, sem, *,
+                   np_: int, ps: int, ppb: int, window: int):
     b = pl.program_id(0)
-    s = pl.program_id(2)
-    is_warm = bt_ref[b, s] < 0
-    k = jnp.where(is_warm, _dequant(k8_ref, ks_ref),
-                  kh_ref[0, 0].astype(jnp.float32))       # [ps, D]
-    v = jnp.where(is_warm, _dequant(v8_ref, vs_ref),
-                  vh_ref[0, 0].astype(jnp.float32))
-    _flash_step(s, np_, ps, window, len_ref[b], q_ref, k, v, o_ref, m_s,
-                l_s, acc_s)
+    G, D = q_ref.shape[1], q_ref.shape[3]
+    T = ppb * ps
+    len_b = len_ref[b]
+    hi_page = jax.lax.div(len_b + ps - 1, ps)
+    lo_page = (jax.lax.div(jnp.maximum(len_b - window, 0), ps) if window
+               else 0)
+    first = jax.lax.div(lo_page, ppb)
+    last = jax.lax.div(hi_page + ppb - 1, ppb)
+
+    def pages(blk, visit, init):
+        """Fold ``visit(j, loc, live, carry)`` over the pages of a block:
+        ``loc`` is page j's encoded location, 0 outside the live range,
+        so such a page is never fetched."""
+        def page_j(j, carry):
+            p = blk * ppb + j
+            live = (p >= lo_page) & (p < hi_page)
+            e = jnp.where(live, bt_ref[b, jnp.minimum(p, np_ - 1)], 0)
+            return visit(j, e, live, carry)
+        # unrolled when lowered: the body is traced once (tracing is part
+        # of every engine's set-up) and runs without loop overhead
+        return jax.lax.fori_loop(0, ppb, page_j, init, unroll=True)
+
+    def dma(blk, slot, op):
+        """Start or wait for a block's copies, each page from its tier."""
+        def page(j, e, live, n_warm):
+            h, w = jnp.maximum(e, 0), jnp.maximum(-e, 0)
+            for cond, pairs in (
+                    (e > 0, ((kh_hbm.at[h], kh_buf), (vh_hbm.at[h], vh_buf))),
+                    (e < 0, ((k8_hbm.at[w], k8_buf), (v8_hbm.at[w], v8_buf)))):
+                @pl.when(cond)
+                def _():
+                    for src, dst in pairs:
+                        getattr(pltpu.make_async_copy(
+                            src, dst.at[slot, j], sem.at[slot]), op)()
+            return n_warm + (e < 0).astype(jnp.int32)
+
+        @pl.when(pages(blk, page, 0) > 0)
+        def _():
+            keys = pl.ds(pl.multiple_of(blk * T, T), ks_buf.shape[-1])
+            for src, dst in ((ksr_hbm, ks_buf), (vsr_hbm, vs_buf)):
+                getattr(pltpu.make_async_copy(
+                    src.at[b, :, keys], dst.at[slot], sem.at[slot]), op)()
+
+    def attend(blk, k_of, v_of, page_ok=None, scales=None):
+        """Online-softmax accumulation of one block for every head;
+        ``k_of(g)`` / ``v_of(g)`` give the block's [ppb, ps, D] tiles,
+        ``scales(g)`` each key's K and V scale rows ([1, T])."""
+        pos = blk * T + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+        valid = _valid(pos, len_b, window)
+        if page_ok is not None:
+            valid &= page_ok
+        # the value rows' mask from its own iota: Mosaic cannot reshape
+        # the (1, T) mask into (T, 1)
+        row_ok = _valid(blk * T + jax.lax.broadcasted_iota(
+            jnp.int32, (T, 1), 0), len_b, window)
+        for g in range(G):
+            q = q_ref[0, g].astype(jnp.float32)           # [group, D]
+            k = k_of(g).astype(jnp.float32).reshape(T, D)
+            logits = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)       # [group, T]
+            if scales is not None:
+                k_sc, v_sc = scales(g)
+                logits = logits * k_sc
+            logits = jnp.where(valid, logits * (D ** -0.5), NEG_INF)
+            m_prev = m_s[g]
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(logits, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.where(valid, jnp.exp(logits - m_new), 0.0)
+            # select, don't rely on the zero weight: rows outside the
+            # window or the length may hold non-finite garbage and
+            # 0 * NaN = NaN
+            v = jnp.where(row_ok,
+                          v_of(g).astype(jnp.float32).reshape(T, D), 0.0)
+            l_s[g] = l_s[g] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            pv = p * v_sc if scales is not None else p
+            acc_s[g] = acc_s[g] * alpha + jax.lax.dot_general(
+                pv, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_s[g] = m_new
+
+    def mixed(blk, slot):
+        """A block holding warm or trash pages: stage every page in f32
+        (hot cast, warm int8 cast, unfetched zero), mask the unfetched
+        keys, scale the warm ones."""
+        row = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+
+        def stage(j, e, live, masks):
+            for st, hot, q8 in ((k_st, kh_buf, k8_buf),
+                                (v_st, vh_buf, v8_buf)):
+                @pl.when(e > 0)
+                def _():
+                    st[j] = hot[slot, j].astype(jnp.float32)
+
+                @pl.when(e < 0)
+                def _():
+                    st[j] = q8[slot, j].astype(jnp.float32)
+
+                @pl.when(e == 0)
+                def _():
+                    st[j] = jnp.zeros(st.shape[1:], jnp.float32)
+            in_page = ((row >= j * ps) & (row < (j + 1) * ps)).astype(
+                jnp.int32)
+            fetched, warm = masks
+            return (fetched | in_page * (e != 0).astype(jnp.int32),
+                    warm | in_page * (e < 0).astype(jnp.int32))
+
+        zero = jnp.zeros((1, T), jnp.int32)
+        fetched, warm = pages(blk, stage, (zero, zero))
+
+        def scales(g):
+            # select: the rows of non-warm keys may be stale or garbage
+            return tuple(jnp.where(warm > 0, buf[slot, pl.ds(g, 1), :T], 1.0)
+                         for buf in (ks_buf, vs_buf))
+
+        attend(blk, lambda g: k_st[:, g], lambda g: v_st[:, g], fetched > 0,
+               scales)
+
+    def block(blk, carry):
+        slot = jax.lax.rem(blk - first, 2)
+
+        @pl.when(blk + 1 < last)
+        def _():
+            dma(blk + 1, 1 - slot, "start")
+
+        dma(blk, slot, "wait")
+        # a block whose live pages are all hot reads its DMA buffers as
+        # they are (the positional mask covers the pages outside the live
+        # range); one with no fetched page adds nothing
+        n_other, n_fetched = pages(
+            blk, lambda j, e, live, n: (
+                n[0] + (live & (e <= 0)).astype(jnp.int32),
+                n[1] + (e != 0).astype(jnp.int32)), (0, 0))
+
+        @pl.when(n_other == 0)
+        def _():
+            attend(blk, lambda g: kh_buf[slot, :, g],
+                   lambda g: vh_buf[slot, :, g])
+
+        @pl.when((n_other > 0) & (n_fetched > 0))
+        def _():
+            mixed(blk, slot)
+        return carry
+
+    m_s[...] = jnp.full_like(m_s, NEG_INF)
+    l_s[...] = jnp.zeros_like(l_s)
+    acc_s[...] = jnp.zeros_like(acc_s)
+
+    @pl.when(first < last)
+    def _():
+        dma(first, 0, "start")
+
+    jax.lax.fori_loop(first, last, block, 0)
+    for g in range(G):
+        o_ref[0, g] = (acc_s[g] / jnp.maximum(l_s[g], 1e-30)
+                       ).astype(o_ref.dtype)
 
 
 def paged_decode_attn_tiered(q, kh_pool, vh_pool, k8_pool, ks_pool, v8_pool,
@@ -182,51 +392,53 @@ def paged_decode_attn_tiered(q, kh_pool, vh_pool, k8_pool, ks_pool, v8_pool,
     q: [B, H, D]; hot pools bf16[P_hot, G, ps, D]; warm pools
     int8[P_warm, G, ps, D] + f32[P_warm, G, ps] scales; block_table:
     int32[B, n_pages] encoded locations (>0 hot, <0 warm, 0 trash);
-    lengths: int32[B] valid-token counts -> [B, H, D]."""
+    lengths: int32[B] valid-token counts -> [B, H, D].  A lane of length
+    0, or whose live pages are all trash, reads zeros."""
     B, H, D = q.shape
     _, G, ps, _ = kh_pool.shape
     group = H // G
     np_ = block_table.shape[1]
+    ppb = _pages_per_block(ps, G, D, np_)
+    T = ppb * ps
+    row_w = -(-T // _LANE_TILE) * _LANE_TILE   # a block's scale-row DMA
+    n_blocks = -(-np_ // ppb)
+    ks_rows, vs_rows = _scale_rows(ks_pool, vs_pool, block_table,
+                                   (n_blocks - 1) * T + row_w)
     q4 = q.reshape(B, G, group, D)
-    kernel = functools.partial(_tiered_kernel, np_=np_, ps=ps, window=window)
-    hot_map = lambda b, g, s, L, BT: (jnp.maximum(BT[b, s], 0), g, 0, 0)
-    warm_map = lambda b, g, s, L, BT: (jnp.maximum(-BT[b, s], 0), g, 0, 0)
-    wscale_map = lambda b, g, s, L, BT: (jnp.maximum(-BT[b, s], 0), 0, 0)
+    kernel = functools.partial(_tiered_kernel, np_=np_, ps=ps, ppb=ppb,
+                               window=window)
+    lane = pl.BlockSpec((1, G, group, D), lambda b, L, BT: (b, 0, 0, 0),
+                        memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+
+    def page_bufs(pool):
+        return pltpu.VMEM((2, ppb) + pool.shape[1:], pool.dtype)
+
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(B, G, np_),
-            in_specs=[
-                pl.BlockSpec((1, 1, group, D),
-                             lambda b, g, s, L, BT: (b, g, 0, 0),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, 1, ps, D), hot_map,
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, 1, ps, D), warm_map,
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, G, ps), wscale_map,
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, 1, ps, D), hot_map,
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, 1, ps, D), warm_map,
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, G, ps), wscale_map,
-                             memory_space=pltpu.VMEM),
-            ],
-            out_specs=pl.BlockSpec((1, 1, group, D),
-                                   lambda b, g, s, L, BT: (b, g, 0, 0)),
+            grid=(B,),
+            in_specs=[lane] + [hbm] * 6,
+            out_specs=lane,
             scratch_shapes=[
-                pltpu.VMEM((group, 1), jnp.float32),
-                pltpu.VMEM((group, 1), jnp.float32),
-                pltpu.VMEM((group, D), jnp.float32),
+                page_bufs(kh_pool), page_bufs(vh_pool), page_bufs(k8_pool),
+                page_bufs(v8_pool),
+                pltpu.VMEM((2, G, row_w), jnp.float32),
+                pltpu.VMEM((2, G, row_w), jnp.float32),
+                pltpu.VMEM((ppb, G, ps, D), jnp.float32),
+                pltpu.VMEM((ppb, G, ps, D), jnp.float32),
+                pltpu.VMEM((G, group, 1), jnp.float32),
+                pltpu.VMEM((G, group, 1), jnp.float32),
+                pltpu.VMEM((G, group, D), jnp.float32),
+                pltpu.SemaphoreType.DMA((2,)),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, G, group, D), out_dtype),
         interpret=pallas_interpret(interpret),
         name="paged_decode_attn_tiered",
-    )(lengths, block_table, q4, kh_pool, k8_pool, ks_pool, vh_pool, v8_pool,
-      vs_pool)
+    )(lengths, block_table, q4, kh_pool, vh_pool, k8_pool, v8_pool, ks_rows,
+      vs_rows)
     return out.reshape(B, H, D)
 
 

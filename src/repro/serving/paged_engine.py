@@ -276,6 +276,13 @@ class PagedEngine(EngineBase):
             "engine_admissions_total", "requests admitted (prefilled)")
         self._c_retire = metrics.counter(
             "engine_retirements_total", "requests retired (EOS or budget)")
+        # block-table entries one attention layer's paged kernel call
+        # reads (a lane's pages up to its length) or skips (idle lanes,
+        # pages past the length), per decode dispatch
+        self._c_attn_pages = {k: metrics.counter(
+            "paged_attn_pages_total",
+            "block-table pages one paged attention layer reads or skips "
+            "per decode dispatch", kind=k) for k in ("read", "skipped")}
         self._h_bucket = metrics.histogram(
             "engine_prefill_bucket_tokens",
             "padded prompt-bucket length per prefill", TOKENS_BUCKETS)
@@ -930,6 +937,14 @@ class PagedEngine(EngineBase):
                                               self.rng, tick)
                 if probe is not None:
                     probe.record_dispatch(time.perf_counter() - t0)
+                # the kernel sees each live lane's length plus this tick's
+                # token; idle lanes (length 0) map only trash
+                live = self._lengths[self._lengths > 0]
+                read = int(((live + self.pool.page_size)
+                            // self.pool.page_size).sum())
+                self._c_attn_pages["read"].inc(read)
+                self._c_attn_pages["skipped"].inc(
+                    self.n_lanes * self.maxp - read)
             if probe is not None and probe.should_fence(self.tick_no):
                 # execution-true sample: drain the device queue through
                 # this tick (dispatch start -> result ready, backlog

@@ -445,3 +445,41 @@ def test_serving_micro_trace_smoke(tmp_path):
     n = run_trace(str(tmp_path), smoke=True)
     assert n > 0
     assert sum(e[0] == "engine.step" for e in read_spans(tmp_path)) == n
+
+
+def test_paged_attn_pages_counted(served_model):
+    """``paged_attn_pages_total{kind}``, from the host's length mirror at
+    each decode dispatch: ``read`` adds ceil(len / ps) for every live lane
+    (len counts this tick's token), ``read + skipped`` every entry of
+    every lane's table."""
+    from repro.cache import TierConfig
+    from repro.serving.paged_engine import PagedEngine
+    cfg, model, params = served_model
+    ps, lanes, max_len = 16, 3, 64
+    eng = PagedEngine(model, params, lanes=lanes, max_len=max_len,
+                      tier=TierConfig(page_size=ps, hbm_budget_bytes=1 << 30,
+                                      enable_warm=False, enable_cold=False),
+                      use_roofline_trigger=False)
+    seen = []
+    decode = eng._decode
+
+    def spy(params, pools, tokens, bt, lengths, *rest):
+        seen.append(np.asarray(lengths).copy())
+        return decode(params, pools, tokens, bt, lengths, *rest)
+
+    spy._cache_size = decode._cache_size        # the compile count probe
+    eng._decode = spy
+    rng = np.random.default_rng(3)
+    # prompts that put lengths on page boundaries (15 + 1 = 16 keys, 31 + 1)
+    for rid, (plen, new) in enumerate([(15, 3), (31, 6), (5, 2), (40, 4)]):
+        eng.submit(Request(rid=rid,
+                           prompt=[int(t) for t in rng.integers(2, 400, plen)],
+                           max_new=new))
+    assert len(eng.run()) == 4
+    m = eng.obs.metrics
+    read = m.get_value("paged_attn_pages_total", kind="read")
+    skipped = m.get_value("paged_attn_pages_total", kind="skipped")
+    want = sum(-(-(int(n) + 1) // ps) for ls in seen for n in ls if n > 0)
+    assert read == want > 0
+    assert read + skipped == len(seen) * lanes * (max_len // ps)
+    assert any((ls == 0).any() for ls in seen)      # an idle lane was seen

@@ -22,6 +22,7 @@ import pytest
 from repro.cache import TierConfig
 from repro.configs import ARCHS, reduced
 from repro.configs.base import MoEConfig
+from repro.kernels.decode_attn import ops as attn_ops
 from repro.kernels.decode_attn.ops import attn_backend_names
 from repro.models import transformer as T
 from repro.models.model import build_model
@@ -184,3 +185,96 @@ def test_tiered_kernel_matches_gather_backend(rng):
         out2 = ops.attn_backend_pallas(q, pools, bt, lengths, window=window)
         np.testing.assert_allclose(np.asarray(out2, np.float32),
                                    np.asarray(ref, np.float32), atol=2e-2)
+
+
+# -- the tiered kernel's iteration space -------------------------------------
+#
+# One jitted kernel and one gather per (geometry, window), shared by the
+# cases below, so each shape compiles once.  Page size 16 and 20-page
+# tables give two 16-page blocks, the second partial.  The trash slot of
+# every pool holds NaN: the kernel must never fetch it, and must select,
+# not multiply, wherever a stale row could reach the sums.
+
+_GEOMS = {"qwen2-7b": (4, 7, 128), "starcoder2-3b": (2, 12, 128)}
+_PS, _NP, _LANES = 16, 20, 3
+_tiered_jit = jax.jit(attn_ops.attn_backend_pallas_int8,
+                      static_argnames=("window", "has_warm"))
+_gather_jit = jax.jit(attn_ops.attn_backend_gather,
+                      static_argnames=("window", "has_warm"))
+
+# name: (geometry, window, tiers of the live pages, lane lengths, what the
+# entries past each length hold[, lanes whose whole table is trash])
+_KERNEL_CASES = {
+    "all_hot": ("qwen2-7b", 0, "hot", (320, 100, 17), "trash"),
+    "all_warm": ("qwen2-7b", 0, "warm", (320, 100, 17), "trash"),
+    "mixed_block_boundary": ("qwen2-7b", 0, "mixed", (300, 256, 33),
+                             "trash"),
+    "page_boundaries": ("qwen2-7b", 0, "mixed", (32, 16, 48), "trash"),
+    # lane 2 as the engine dispatches an idle lane: this tick's token
+    # (length 1) over an all-trash table
+    "idle_lanes": ("qwen2-7b", 0, "mixed", (0, 200, 1), "trash", (2,)),
+    "pages_past_length": ("qwen2-7b", 0, "mixed", (100, 5, 250), "pages"),
+    "starcoder_hot": ("starcoder2-3b", 0, "hot", (320, 257, 1), "trash"),
+    "starcoder_mixed": ("starcoder2-3b", 0, "mixed", (320, 200, 64),
+                        "pages"),
+    "window_past_block0": ("qwen2-7b", 40, "mixed", (300, 320, 30),
+                           "trash"),
+    "window_hot": ("qwen2-7b", 40, "hot", (290, 64, 256), "trash"),
+}
+
+
+def _kernel_case(name):
+    geom, window, tiers, lengths, past, *trash = _KERNEL_CASES[name]
+    G, group, D = _GEOMS[geom]
+    rng = np.random.default_rng(sum(map(ord, name)))
+    n = _LANES * _NP
+    shp, sc = (1 + n, G, _PS, D), (1 + n, G, _PS)
+    pools = {
+        "kh": rng.standard_normal(shp).astype(np.float32),
+        "vh": rng.standard_normal(shp).astype(np.float32),
+        "k8": rng.integers(-127, 128, shp).astype(np.float32),
+        "v8": rng.integers(-127, 128, shp).astype(np.float32),
+        "ks": rng.uniform(0.005, 0.02, sc).astype(np.float32),
+        "vs": rng.uniform(0.005, 0.02, sc).astype(np.float32),
+    }
+    for a in ("kh", "vh", "ks", "vs"):
+        pools[a][0] = np.nan                     # the trash slot
+    pools = {k: jnp.asarray(v, {"kh": jnp.bfloat16, "vh": jnp.bfloat16,
+                                "k8": jnp.int8, "v8": jnp.int8}.get(
+                                    k, jnp.float32))
+             for k, v in pools.items()}
+    slots = rng.permutation(np.arange(1, n + 1)).reshape(_LANES, _NP)
+    warm = {"hot": np.zeros((_LANES, _NP), bool),
+            "warm": np.ones((_LANES, _NP), bool),
+            "mixed": rng.random((_LANES, _NP)) < 0.5}[tiers]
+    bt = np.where(warm, -slots, slots)
+    live = np.arange(_NP)[None, :] < -(-np.asarray(lengths)[:, None] // _PS)
+    if past == "trash":
+        bt = np.where(live, bt, 0)
+    for lane in trash[0] if trash else ():
+        bt[lane] = 0
+    q = jnp.asarray(rng.standard_normal((_LANES, G * group, D)),
+                    jnp.bfloat16)
+    return (q, pools, jnp.asarray(bt, jnp.int32),
+            jnp.asarray(lengths, jnp.int32), window)
+
+
+@pytest.mark.parametrize("case", list(_KERNEL_CASES))
+def test_tiered_kernel_iteration_space(case):
+    """The tiered kernel, which fetches each live page from its own tier
+    in double-buffered blocks, against the gather backend: all-hot,
+    all-warm and mixed blocks, trash or stale pages past the length,
+    idle lanes, lengths on page and block boundaries and at the full
+    table, a table that is not a whole number of blocks, and a window
+    whose first live block is past block 0."""
+    q, pools, bt, lengths, window = _kernel_case(case)
+    out = np.asarray(_tiered_jit(q, pools, bt, lengths, window=window),
+                     np.float32)
+    ref = np.asarray(_gather_jit(q, pools, bt, lengths, window=window),
+                     np.float32)
+    assert np.isfinite(out).all()
+    # a lane with no key or no fetched page reads zeros (the gather
+    # backend attends to the trash slot there)
+    empty = (np.asarray(lengths) == 0) | (np.asarray(bt) == 0).all(axis=1)
+    assert not out[empty].any()
+    np.testing.assert_allclose(out[~empty], ref[~empty], atol=2e-2)
