@@ -19,7 +19,7 @@ import time
 
 import numpy as np
 
-from bench import flops as F
+from bench import families
 from bench.traffic import gen
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -45,7 +45,8 @@ def manifest(root: pathlib.Path = ROOT) -> dict:
 
 
 def load_cell(name: str, root: pathlib.Path = ROOT):
-    """(cell, configuration, mix, limits) of one ``workloads`` entry."""
+    """(cell, configuration, mix, limits) of one ``workloads`` entry.
+    Raises where the configuration names no family module that exists."""
     m = manifest(root)
     cells = {c["name"]: c for c in m["workloads"]}
     if name not in cells:
@@ -53,6 +54,7 @@ def load_cell(name: str, root: pathlib.Path = ROOT):
     cell = cells[name]
     conf = json.loads((root / "bench" / "configs"
                        / f"{cell['config']}.json").read_text())
+    families.path(conf, root)
     mix = gen.load_mix(cell["traffic"], root / "bench" / "traffic")
     lim_path = root / "bench" / "limits" / f"{name}.json"
     limits = json.loads(lim_path.read_text()) if lim_path.is_file() else {}
@@ -72,23 +74,6 @@ def device_info(chips: int, require_tpu: bool = True) -> dict:
 
 
 # -- the system under test ----------------------------------------------------
-
-def arch_config(conf: dict):
-    """The program's ArchConfig for a configuration file: the registered
-    architecture with every size the file states."""
-    import dataclasses as dc
-    from repro.configs import get_arch
-    c = conf["config"]
-    act = {"silu": "silu", "gelu_pytorch_tanh": "gelu"}[c["hidden_act"]]
-    norm = "layernorm" if c.get("norm_type") == "layer_norm" else "rmsnorm"
-    return dc.replace(
-        get_arch(conf["arch"]), n_layers=c["num_hidden_layers"],
-        d_model=c["hidden_size"], n_heads=c["num_attention_heads"],
-        n_kv_heads=c["num_key_value_heads"], d_ff=c["intermediate_size"],
-        vocab_size=c["vocab_size"], rope_theta=float(c["rope_theta"]),
-        tie_embeddings=bool(c["tie_word_embeddings"]), act=act, norm=norm,
-        qkv_bias=True)
-
 
 def build_engine(conf: dict, mix: dict, params, model, seed: int):
     from repro.assist import AssistSpec
@@ -194,10 +179,12 @@ class Driver:
 
 @dataclasses.dataclass
 class Setup:
-    """What set-up builds for a cell: its files, the device, the weights
-    drawn from the seed, and the engine with the driver that feeds it."""
+    """What set-up builds for a cell: its files and family module, the
+    device, the weights drawn from the seed, and the engine with the
+    driver that feeds it."""
     cell: dict
     conf: dict
+    fam: object
     mix: dict
     limits: dict
     device: dict
@@ -212,6 +199,7 @@ def set_up(name: str, seed: int, *, root: pathlib.Path = ROOT,
     """Everything before the warm-up: the look for the chip, the compile
     cache, the weights drawn on the device from ``seed``, the engine."""
     cell, conf, mix, limits = load_cell(name, root)
+    fam = families.load(conf, root)
     device = device_info(cell["chips"], require_tpu)
     import jax
     if compile_cache:
@@ -221,13 +209,13 @@ def set_up(name: str, seed: int, *, root: pathlib.Path = ROOT,
     from repro.models.model import build_model
     from bench import weights as W
 
-    cfg = arch_config(conf)
+    cfg = fam.arch(conf)
     model = build_model(cfg)
-    params = W.make_params(conf["config"], seed)
+    params = fam.make_params(conf["config"], seed)
     W.check_layout(params, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
     eng = build_engine(conf, mix, params, model, seed)
-    return Setup(cell, conf, mix, limits, device, cfg.vocab_size, params,
-                 eng, Driver(eng, seed))
+    return Setup(cell, conf, fam, mix, limits, device, cfg.vocab_size,
+                 params, eng, Driver(eng, seed))
 
 
 def warm_up(drv: Driver, plans: list, max_len: int) -> None:
@@ -312,17 +300,17 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         from bench import metrics as M
         ctx = dict(res["ctx"], conf=conf, mix=mix,
                    peaks=_peaks(device, require_tpu),
-                   shapes=F.shapes(conf["config"]),
+                   counts=s.fam.counts(conf["config"]),
                    page_size=s.eng.pool.page_size, window_s=res["window_s"])
         per_layer = M.read_all(cell, ctx, root)
         device["busy_s"] = res["ctx"]["trace"].busy_s
         device["window_s"] = res["ctx"]["trace"].window_s
 
     # the check runs after the program's state is freed
-    params = s.params
+    params, fam = s.params, s.fam
     del s
     gc.collect()
-    check, readings = _check(conf, mix, limits, params, answers, seed,
+    check, readings = _check(fam, conf, mix, limits, params, answers, seed,
                              failed, control)
     correct = all(_holds(v) for v in check.values())
     if readings is not None:
@@ -674,17 +662,17 @@ def _sample(answers, seed: int, want_tokens: int, max_requests: int):
     return out
 
 
-def _check(conf, mix, limits, params, answers, seed, failed, control):
-    """Compare the served tokens of a sample with the float32 reference:
-    the widest gap by which a served token's logit lies below the
-    reference's best. Every answer must also be whole.
+def _check(fam, conf, mix, limits, params, answers, seed, failed, control):
+    """Compare the served tokens of a sample with the family's float32
+    reference (``fam.gaps``): the widest gap by which a served token's
+    logit lies below the reference's best. Every answer must also be
+    whole.
 
     With ``control`` (a precision below the configuration's, ``int8`` or
     ``fp8``) the reference at that precision stands in the program's
     place: at the same positions, the tokens it puts first are compared,
     and the program's tokens are read beside them."""
     import jax.numpy as jnp
-    from bench import reference as R
     chk = mix.get("check", {})
     sample = _sample(answers, seed, int(chk.get("sample_tokens", 300)),
                      int(chk.get("max_requests", 8)))
@@ -702,11 +690,11 @@ def _check(conf, mix, limits, params, answers, seed, failed, control):
         cand = np.zeros((S, len(kinds)), np.int32)
         cand[pos, 0] = out
         if control:
-            _, am = R.gaps(conf["config"], params, jnp.asarray(full),
-                           jnp.asarray(cand[:, :1]), quantize=control)
+            _, am = fam.gaps(conf["config"], params, jnp.asarray(full),
+                             jnp.asarray(cand[:, :1]), quantize=control)
             cand[pos, 1] = np.asarray(am)[pos]
-        g, _ = R.gaps(conf["config"], params, jnp.asarray(full),
-                      jnp.asarray(cand))
+        g, _ = fam.gaps(conf["config"], params, jnp.asarray(full),
+                        jnp.asarray(cand))
         g = np.asarray(g)[pos]
         for j, k in enumerate(kinds):
             gaps[k].extend(g[:, j].tolist())

@@ -49,19 +49,20 @@ def prefill_ms_per_ktok(ctx):
 def paged_attn_roofline(ctx):
     """Least time over kernel time, summed over the traced decode steps:
     the least time of a step is every layer's paged-attention call at the
-    lanes' contexts, bounded by the larger of FLOPs and page bytes."""
+    lanes' contexts (the family's ``counts.attn_step_cost``), bounded by
+    the larger of FLOPs and page bytes."""
     tr = ctx.get("trace")
     steps = ctx.get("steps") or []
     n_k, t_k = tr.op_time_s(ATTN_KERNEL) if tr else (0, 0.0)
     if not n_k or not steps:
         return None
-    s, ps, peaks = ctx["shapes"], ctx["page_size"], ctx["peaks"]
+    c, ps, peaks = ctx["counts"], ctx["page_size"], ctx["peaks"]
     n_dec = tr.module_time_s(DECODE)[0]
     least = []
     for _, ctxs, warm_of_lanes, _ in steps:
         if ctxs:
-            fl, by = F.paged_attn_cost(s, ctxs, ps, warm_of_lanes)
-            least.append(s.layers * F.least_time(fl, by, peaks))
+            fl, by = c.attn_step_cost(ctxs, ps, warm_of_lanes)
+            least.append(F.least_time(fl, by, peaks))
     if not least or not n_dec:
         return None
     # the traced decode programs, each charged the mean step's least time
@@ -70,14 +71,15 @@ def paged_attn_roofline(ctx):
 
 def step_mfu(ctx):
     """Model FLOPs of every token processed in the traced stretch (true
-    prompt tokens and decoded tokens) over busy time at the bf16 peak."""
+    prompt tokens and decoded tokens; the family's ``counts``) over busy
+    time at the bf16 peak."""
     tr = ctx.get("trace")
     if tr is None or tr.busy_s <= 0:
         return None
-    s = ctx["shapes"]
-    fl = sum(F.prefill_flops(s, n) for n in ctx.get("prefill_tokens", []))
+    c = ctx["counts"]
+    fl = sum(c.prefill_flops(n) for n in ctx.get("prefill_tokens", []))
     for _, ctxs, _, _ in ctx.get("steps") or []:
-        fl += sum(F.decode_token_flops(s, c) for c in ctxs)
+        fl += sum(c.decode_token_flops(x) for x in ctxs)
     if fl <= 0:
         return None
     return fl / (tr.busy_s * ctx["peaks"]["bf16_flops"]) * 100.0

@@ -4,8 +4,9 @@ A reader defines ``read(ctx) -> float | None``. ``ctx`` holds the reduced
 trace (``trace``), the host's samples of the traced stretch (``steps``,
 ``prefill_tokens``), the requests (``recs``), generator lateness
 (``gen_lag_ms``), tier samples (``tier_samples``, ``cold_moves``), the
-configuration, mix, peaks and shapes. A reader that finds nothing to read
-returns None, and the metric is left out of the line.
+configuration, mix, peaks, and the family's FLOP and byte counts
+(``counts``). A reader that finds nothing to read returns None, and the
+metric is left out of the line.
 """
 from __future__ import annotations
 

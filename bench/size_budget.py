@@ -28,16 +28,16 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     sys.path.insert(0, str(ROOT / "src"))
     import jax
+    from bench import families
     from bench import harness as H
-    from bench import weights as W
     from repro.models.model import build_model, prompt_bucket
     from repro.serving.engine import stage_host
 
     cell, conf, mix, _ = H.load_cell(args.workload)
+    fam = families.load(conf)
     H.device_info(cell["chips"])
-    cfg = H.arch_config(conf)
-    model = build_model(cfg)
-    params = W.make_params(conf["config"], args.seed)
+    model = build_model(fam.arch(conf))
+    params = fam.make_params(conf["config"], args.seed)
     eng = H.build_engine(conf, mix, params, model, args.seed)
     ps, max_len = eng.pool.page_size, mix["serve"]["max_len"]
 

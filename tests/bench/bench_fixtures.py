@@ -13,7 +13,7 @@ import shutil
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 
 TINY_CONFIG = {
-    "name": "tiny", "arch": "qwen2-7b",
+    "name": "tiny", "arch": "qwen2-7b", "family": "dense_gqa",
     "source": "test-only configuration",
     "config": {"hidden_act": "silu", "hidden_size": 64,
                "intermediate_size": 128, "num_attention_heads": 4,

@@ -11,7 +11,7 @@ from types import SimpleNamespace as NS
 import numpy as np
 import pytest
 
-from bench import flops as F
+from bench import families
 from bench import layer_metrics as LM
 from bench import metrics as M
 from bench import trace_reduce as T
@@ -123,6 +123,6 @@ def test_trace_readers_read_as_before(chip_trace, metric):
     steps = [(0.1 * k, [600 + k + 37 * i for i in range(lanes)], 0.0, 0.0)
              for k, lanes in enumerate((32, 32, 30))]
     ctx = {"trace": chip_trace, "steps": steps, "prefill_tokens": [577],
-           "shapes": F.shapes(conf["config"]), "page_size": 16,
-           "peaks": PEAKS["TPU v5 lite"]}
+           "counts": families.load(conf).counts(conf["config"]),
+           "page_size": 16, "peaks": PEAKS["TPU v5 lite"]}
     assert M.reader(metric)(ctx) == pytest.approx(PINNED[metric], rel=1e-12)
